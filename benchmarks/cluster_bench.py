@@ -64,6 +64,7 @@ from repro.core import compiler  # noqa: E402
 from repro.core.compiler import (cluster_cost,
                                  iso_performance_cores)  # noqa: E402
 from repro.kernels import registry  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 RNG = np.random.default_rng(0)
 
@@ -321,6 +322,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--out", default="BENCH_cluster.json",
                     help="output JSON path (default: %(default)s)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     # deterministic provenance: executed rows resolve per-core schedules
     # from the cache, so the sweep isolates it unless explicitly shared
     isolate_schedule_cache()

@@ -76,6 +76,7 @@ import numpy as np
 from repro.core.lowering import DEFAULT_SCHEDULE, Schedule
 from repro.kernels import registry
 from repro.kernels.chained import fused_cases
+from repro.launch.compile_cache import enable_compile_cache
 
 RNG = np.random.default_rng(0)
 
@@ -1498,6 +1499,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                     help="per-run summary JSONL (default: %(default)s); "
                          "'' disables")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     isolate_schedule_cache()
 
     if args.chaos_smoke:

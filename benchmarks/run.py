@@ -20,6 +20,9 @@ import os
 
 def main() -> None:
     from benchmarks import kernel_bench, paper_tables
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     rows = []
     for fn in paper_tables.ALL:

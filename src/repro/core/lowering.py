@@ -855,6 +855,11 @@ def lower_nest(plan: StreamPlan,
 
     rescale = out_ref.acc_kind == "online_softmax"
     if rescale:
+        if not zaxes:
+            # A one-key KV level (bound 1) still carries the (m, l, acc)
+            # triple — over a single step.  ``contraction_axes`` drops
+            # bound-1 levels, so recover the revisited level here.
+            zaxes = tuple(k for k, c in enumerate(out_ref.coeffs) if c == 0)
         if len(zaxes) != 1:
             raise LoweringError(
                 f"output ref '{out_ref.name}': online_softmax needs exactly "
@@ -1124,9 +1129,13 @@ _PLAN_CACHES = (_plan_for, plan_stats, _chain_for, _dag_for, _lowered_for,
 #: default (cache I/O fault before any kernel was built), ``degraded``
 #: counts committed tuned schedules that failed to lower/compile/run and
 #: were quarantined + re-dispatched on the default schedule.  Healthy
-#: runs keep both at zero.
+#: runs keep both at zero.  ``pipelined`` counts ``ssr_call`` kernels
+#: built on the explicit N-deep DMA rotation (``fn.pipelined`` of
+#: :func:`~repro.core.ssr.ssr_pallas`) — proof that a ``buffer_depth > 2``
+#: schedule did not silently take the synchronous path.
 DISPATCH_STATS: Dict[str, int] = {"builds": 0, "traces": 0, "calls": 0,
-                                  "fallbacks": 0, "degraded": 0}
+                                  "fallbacks": 0, "degraded": 0,
+                                  "pipelined": 0}
 
 
 def _record_fallback(site: str, error: BaseException, *,
@@ -1922,6 +1931,7 @@ def ssr_call(nest: LoopNest, body: Callable[..., jax.Array],
             resilience.inject("compile")
             fn = jax.jit(pipeline)
             DISPATCH_STATS["builds"] += 1
+            DISPATCH_STATS["pipelined"] += int(kernel.pipelined)
             _kernel_cache_put(key, fn)
         return fn(*arrays, *tables, *[a for _, a in uni])
 

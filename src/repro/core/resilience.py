@@ -230,24 +230,15 @@ def fallback_error_types() -> Tuple[type, ...]:
     """The closed set of error types dispatch may degrade on.
 
     Injected faults, the lowering's own rejection type, cache-I/O
-    ``OSError``, and XLA's runtime/compile error when jax is importable.
+    ``OSError``, and XLA's runtime/compile error.
     Everything else — missing operands (``ValueError``), bad body
     signatures (``TypeError``), numerics — propagates untouched: fallback
     must never mask a genuine user error.
     """
-    types: List[type] = [InjectedFault, OSError]
+    from jax.errors import JaxRuntimeError
+
     from .lowering import LoweringError
-    types.append(LoweringError)
-    try:
-        from jax.errors import JaxRuntimeError
-        types.append(JaxRuntimeError)
-    except ImportError:       # pragma: no cover - older jax
-        try:
-            from jax._src.lib import xla_client
-            types.append(xla_client.XlaRuntimeError)
-        except (ImportError, AttributeError):
-            pass
-    return tuple(types)
+    return (InjectedFault, OSError, LoweringError, JaxRuntimeError)
 
 
 def classify(exc: BaseException) -> str:

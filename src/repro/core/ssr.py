@@ -82,19 +82,12 @@ def pipeline_supported() -> bool:
     """Whether the explicit N-deep DMA rotation can be emitted here.
 
     The rotation needs the Pallas TPU primitives (``make_async_copy``,
-    DMA semaphores, VMEM scratch, the ANY memory space) — available on TPU
-    *and* in interpret mode on this jax version.  Absent primitives, or an
-    explicit ``REPRO_DISABLE_PIPELINE`` opt-out, fall back to the
-    synchronous (depth-2 Pallas pipeline) path; semantics are identical.
+    DMA semaphores, VMEM scratch) and the ANY memory space (``pl.ANY``) —
+    available on TPU *and* in interpret mode.  Only an explicit
+    ``REPRO_DISABLE_PIPELINE`` opt-out falls back to the synchronous
+    (depth-2 Pallas pipeline) path; semantics are identical.
     """
-    if os.environ.get("REPRO_DISABLE_PIPELINE"):
-        return False
-    try:
-        from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
-    except ImportError:  # pragma: no cover - pallas always ships tpu here
-        return False
-    return all(hasattr(pltpu, attr) for attr in
-               ("make_async_copy", "SemaphoreType", "VMEM", "TPUMemorySpace"))
+    return not os.environ.get("REPRO_DISABLE_PIPELINE")
 
 
 def _on_tpu() -> bool:
@@ -184,15 +177,10 @@ def _unique_blocks(stream: BlockStream, grid: Tuple[int, ...]) -> int:
 
 
 def _compiler_params(dimension_semantics: Tuple[str, ...]):
-    """TPU compiler params across jax versions (TPUCompilerParams is the
-    0.4.x name; CompilerParams the newer one)."""
+    """Mosaic compiler params: the grid axes' dimension semantics."""
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
-    cls = getattr(pltpu, "TPUCompilerParams",
-                  getattr(pltpu, "CompilerParams", None))
-    if cls is None:  # pragma: no cover - one of the two always exists
-        return None
-    return cls(dimension_semantics=dimension_semantics)
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
 def _flat_strides(grid: Tuple[int, ...]) -> Tuple[int, ...]:
@@ -336,7 +324,7 @@ def _pipelined_call(
         call = pl.pallas_call(
             wrapped,
             grid=grid,
-            in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)
                       for _ in in_streams],
             out_specs=[s.spec() for s in out_streams]
             if n_out != 1 else out_streams[0].spec(),
@@ -428,9 +416,7 @@ def ssr_pallas(
         # sequential regardless of the caller's declared semantics
         dimension_semantics = ("arbitrary",) * len(grid)
     if dimension_semantics is not None and not interpret:
-        params = _compiler_params(dimension_semantics)
-        if params is not None:
-            kwargs["compiler_params"] = params
+        kwargs["compiler_params"] = _compiler_params(dimension_semantics)
     if cost_estimate is not None:
         kwargs["cost_estimate"] = cost_estimate
 

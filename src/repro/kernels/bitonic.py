@@ -3,9 +3,11 @@
 A sort network is data-oblivious: the compare-exchange pattern is fixed, so
 every stage's partner access is an affine (power-of-two strided) stream —
 the reason the paper can SSR-ify a *sort*.  All log²(n)/2-ish stages unroll
-statically; each stage's partner pairing is a reshape to (n/2j, 2, j) and the
-direction mask is computed from a static iota (no data-dependent addressing
-anywhere, so the body is min/max ops only).
+statically.  The vector sits in VMEM as (rows, 128) tiles; element
+``i = row·128 + lane`` meets its partner ``i ^ j`` through two rotations
+(lanes for ``j < 128``, rows otherwise) and a static iota mask picks the
+rotation and the min/max side — no data-dependent addressing and no shape
+casts, which is what Mosaic can lower.
 """
 
 from __future__ import annotations
@@ -14,58 +16,74 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import BlockStream, Direction
 
-from .frontend import Launch, StreamKernel, require_power_of_two
+from .frontend import LANES, Launch, StreamKernel, require_power_of_two
 from .registry import KernelEntry, register_kernel
 
 
+# Every layout is at least one whole (8, 128) 32-bit tile; shorter inputs
+# are padded with the dtype's largest value, which sorts to the tail that
+# ``finish`` trims.
+_MIN_ELEMS = 8 * LANES
+
+
 def _prepare(x):
-    require_power_of_two(x.shape[0], "bitonic network")
-    return (x.reshape(1, -1),), None, None
+    n = x.shape[0]
+    require_power_of_two(n, "bitonic network")
+    pad = max(0, _MIN_ELEMS - n)
+    if pad:
+        top = (jnp.inf if jnp.issubdtype(x.dtype, jnp.floating)
+               else jnp.iinfo(x.dtype).max)
+        x = jnp.pad(x, (0, pad), constant_values=top)
+    return (x.reshape(-1, LANES),), None, n
+
+
+def _partner(x, i, j: int):
+    """``x[i ^ j]`` for every flat index ``i`` of a (rows, LANES) block."""
+    axis, d = (1, j) if j < LANES else (0, j // LANES)
+    size = x.shape[axis]
+    up = pltpu.roll(x, size - d, axis)       # x[i + j]
+    down = pltpu.roll(x, d, axis)            # x[i - j]
+    return jnp.where((i & j) == 0, up, down)
 
 
 def _body(static):
     def body(x_ref, o_ref):
-        n = x_ref.shape[1]
-        x = x_ref[...].reshape(n)
-        stages = int(math.log2(n))
-        for ks in range(1, stages + 1):        # k = 2**ks
+        x = x_ref[...]
+        rows = x.shape[0]
+        n = rows * LANES
+        i = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) * LANES
+             + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))
+        for ks in range(1, int(math.log2(n)) + 1):   # k = 2**ks
             k = 1 << ks
-            for js in range(ks - 1, -1, -1):   # j = 2**js
+            for js in range(ks - 1, -1, -1):          # j = 2**js
                 j = 1 << js
-                X = x.reshape(n // (2 * j), 2, j)
-                a = X[:, 0, :]
-                b = X[:, 1, :]
-                # ascending iff (i & k) == 0; i = q·2j + h·j + r and k ≥ 2j,
-                # so the k-bit of i is carried entirely by q.
-                q = jax.lax.broadcasted_iota(jnp.int32, (n // (2 * j), 1), 0)
-                asc = ((q * 2 * j) & k) == 0
-                lo = jnp.minimum(a, b)
-                hi = jnp.maximum(a, b)
-                first = jnp.where(asc, lo, hi)
-                second = jnp.where(asc, hi, lo)
-                x = jnp.stack([first, second], axis=1).reshape(n)
-        o_ref[...] = x.reshape(1, n)
+                p = _partner(x, i, j)
+                # the lower index of an ascending pair (or the upper of a
+                # descending one) keeps the min
+                keep_min = ((i & j) == 0) == ((i & k) == 0)
+                x = jnp.where(keep_min, jnp.minimum(x, p), jnp.maximum(x, p))
+        o_ref[...] = x
 
     return body
 
 
 def _launch(static, x2d):
-    n = x2d.shape[1]
     return Launch(
         grid=(1,),
-        in_streams=(BlockStream((1, n), lambda i: (0, 0), name="x"),),
-        out_streams=(BlockStream((1, n), lambda i: (0, 0),
+        in_streams=(BlockStream(x2d.shape, lambda i: (0, 0), name="x"),),
+        out_streams=(BlockStream(x2d.shape, lambda i: (0, 0),
                                  Direction.WRITE, name="y"),),
-        out_shapes=(jax.ShapeDtypeStruct((1, n), x2d.dtype),),
+        out_shapes=(jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),),
     )
 
 
 _ssr = StreamKernel(
     "bitonic", prepare=_prepare, launch=_launch, body=_body,
-    finish=lambda out, _: out.reshape(-1),
+    finish=lambda out, n: out.reshape(-1)[:n],
     lowering_waiver=(
         "compare-exchange network: each stage pairs elements at a "
         "different power-of-two distance — data-oblivious and affine per "

@@ -89,6 +89,16 @@ def reset_dispatch_stats() -> None:
         DISPATCH_STATS[k] = 0
 
 
+class MosaicUnsupported(NotImplementedError):
+    """A kernel declared as one Mosaic refuses, called for the chip.
+
+    Raised before lowering when such a kernel is called with
+    ``interpret=False`` (or on a TPU, where ``interpret=None`` means
+    Mosaic).  Not a dispatch-fallback type: the chip never quietly
+    interprets it or hands it to ``ref``.
+    """
+
+
 def _is_arraylike(x: Any) -> bool:
     return hasattr(x, "shape") and hasattr(x, "dtype")
 
@@ -278,8 +288,12 @@ class NestKernel:
     def __init__(self, name: str, *, prepare: Callable, nest: Callable,
                  body: Callable, mode: str = "reduce",
                  finish: Optional[Callable] = None,
-                 out_dtype: Optional[Callable] = None):
+                 out_dtype: Optional[Callable] = None,
+                 mosaic_refusal: str = ""):
         self.name = name
+        # Why Mosaic cannot compile this kernel (the compiler's own reason);
+        # empty for kernels that run on the chip.
+        self.mosaic_refusal = mosaic_refusal
         self._prepare = prepare
         self._nest = nest
         self._body = body
@@ -322,6 +336,11 @@ class NestKernel:
         error (the caller pinned it, masking would hide their bug).
         """
         DISPATCH_STATS["calls"] += 1
+        mosaic = _on_tpu() if interpret is None else not interpret
+        if self.mosaic_refusal and mosaic:
+            raise MosaicUnsupported(
+                f"kernel {self.name!r} does not compile through Mosaic: "
+                f"{self.mosaic_refusal}")
         try:
             return self._dispatch(args, params, interpret, schedule)
         except resilience.fallback_error_types() as e:

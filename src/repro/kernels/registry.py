@@ -61,6 +61,10 @@ class KernelEntry:
                    factory; ``odd=True`` yields non-multiple-of-block sizes
     ``tol``      — allclose tolerances for ssr/baseline-vs-ref comparisons
     ``problem``  — human-readable §4.2 problem description
+    ``mosaic_refusal`` — the compiler's reason, for a kernel declared as
+                   not compiling through Mosaic (its ``ssr`` raises
+                   ``frontend.MosaicUnsupported`` off the interpreter);
+                   empty for kernels that run on the chip
     """
 
     name: str
@@ -72,6 +76,7 @@ class KernelEntry:
     tol: Dict[str, float] = dataclasses.field(
         default_factory=lambda: {"rtol": 1e-3, "atol": 1e-3})
     problem: str = ""
+    mosaic_refusal: str = ""
 
     def variants(self) -> Dict[str, Callable]:
         out = {"ssr": self.ssr, "ref": self.ref}

@@ -5,9 +5,15 @@ lockstep; the carry lives in a VMEM scratch register across grid steps (the
 sequential dependence the paper handles with the accumulator register).  The
 grid dimension is ``arbitrary`` (sequential) — blocks must retire in order,
 but the *fetch* of block i+1 still overlaps the compute of block i.
+
+The in-block prefix is two matmuls against triangular ones matrices (Mosaic
+has no ``cumsum`` lowering): lane prefixes ``x @ U`` within each row, then
+each row's offset as ``L @ row_totals`` — both land on the MXU.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +31,22 @@ def _prepare(x):
     return (pad_vector(x),), None, x.shape[0]
 
 
+def _tri(n: int, strict: bool) -> jax.Array:
+    """``n×n`` ones on and above (or strictly below) the diagonal."""
+    r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    return jnp.where(c < r if strict else r <= c, 1.0, 0.0).astype(jnp.float32)
+
+
+def block_prefix(x: jax.Array) -> jax.Array:
+    """Inclusive row-major prefix sum of a (rows, lanes) f32 block."""
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    rows = dot(x, _tri(x.shape[1], strict=False))        # lane prefixes
+    offs = dot(_tri(x.shape[0], strict=True), rows[:, -1:])  # earlier rows
+    return rows + offs
+
+
 def _ssr_body(static):
     def body(x_ref, o_ref, carry_ref):
         i = pl.program_id(0)
@@ -33,9 +55,9 @@ def _ssr_body(static):
         def _init():
             carry_ref[...] = jnp.zeros_like(carry_ref)
 
-        csum = jnp.cumsum(promote(x_ref[...]).reshape(-1))
-        o_ref[...] = (csum + carry_ref[0, 0]).reshape(ROWS, LANES)
-        carry_ref[...] = (carry_ref[0, 0] + csum[-1]).reshape(1, 1)
+        out = block_prefix(promote(x_ref[...])) + carry_ref[...]
+        o_ref[...] = out
+        carry_ref[...] = out[-1:, -1:]
 
     return body
 
@@ -68,12 +90,11 @@ def _baseline_body(static):
 
         def step(i, carry):
             x = promote(x_ref[pl.dslice(i * ROWS, ROWS), :])
-            csum = jnp.cumsum(x.reshape(-1))
-            o_ref[pl.dslice(i * ROWS, ROWS), :] = (
-                (csum + carry).reshape(ROWS, LANES))
-            return carry + csum[-1]
+            out = block_prefix(x) + carry
+            o_ref[pl.dslice(i * ROWS, ROWS), :] = out
+            return out[-1:, -1:]
 
-        jax.lax.fori_loop(0, nblk, step, jnp.float32(0))
+        jax.lax.fori_loop(0, nblk, step, jnp.zeros((1, 1), jnp.float32))
 
     return body
 

@@ -40,6 +40,13 @@ from repro.core import compiler
 from .frontend import MonolithicKernel, NestKernel, promote
 from .registry import KernelEntry, register_kernel
 
+# Mosaic's gather lowering takes only a 2-D ``take_along_axis`` whose index
+# block has the operand's shape; the indirection stream gathers arbitrary
+# addresses from a flattened VMEM-resident table, so both kernels stay off
+# the chip until the gather is re-lowered (a segment reduce over indptr).
+MOSAIC_REFUSAL = ("Pallas TPU lowering rejects the in-kernel jnp.take on the "
+                  "flattened gather table: 'Only 2D gather is supported'")
+
 # Padded row pitch of the SpMM gather table: the indirect ref's
 # ``index_scale`` must be a static layout fact, so the dense operand is
 # padded to a lane-aligned pitch independent of the (searched) schedule.
@@ -142,7 +149,7 @@ def _body_spmv(static):
 
 
 _ssr_spmv = NestKernel("spmv", prepare=_prepare_spmv, nest=_nest_spmv,
-                       body=_body_spmv)
+                       body=_body_spmv, mosaic_refusal=MOSAIC_REFUSAL)
 
 
 def ssr_spmv(data, indices, indptr, x, *, interpret=None,
@@ -224,7 +231,7 @@ def _body_spmm(static):
 
 
 _ssr_spmm = NestKernel("spmm", prepare=_prepare_spmm, nest=_nest_spmm,
-                       body=_body_spmm)
+                       body=_body_spmm, mosaic_refusal=MOSAIC_REFUSAL)
 
 
 def ssr_spmm(data, indices, indptr, x, *, interpret=None,
@@ -305,6 +312,7 @@ def _entry_spmv() -> KernelEntry:
 
     return KernelEntry(name="spmv", ssr=ssr_spmv, baseline=baseline_spmv,
                        ref=ref_spmv, example=example,
+                       mosaic_refusal=MOSAIC_REFUSAL,
                        tol={"rtol": 1e-5, "atol": 1e-5},
                        problem="CSR 32×32 @ 25% density")
 
@@ -319,5 +327,6 @@ def _entry_spmm() -> KernelEntry:
 
     return KernelEntry(name="spmm", ssr=ssr_spmm, baseline=baseline_spmm,
                        ref=ref_spmm, example=example,
+                       mosaic_refusal=MOSAIC_REFUSAL,
                        tol={"rtol": 1e-5, "atol": 1e-5},
                        problem="CSR 32×32 · 32×16 @ 25% density")

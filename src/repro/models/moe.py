@@ -199,7 +199,7 @@ def _moe_apply_xla(params, x: jax.Array, cfg: ModelConfig
 def _moe_apply_ep(params, x: jax.Array, cfg: ModelConfig, mesh
                   ) -> Tuple[jax.Array, jax.Array]:
     from jax.sharding import PartitionSpec as P  # noqa: PLC0415
-    from jax.experimental.shard_map import shard_map  # noqa: PLC0415
+    from jax import shard_map  # noqa: PLC0415
     from repro.parallel.sharding import dp_axes  # noqa: PLC0415
 
     m: MoEConfig = cfg.moe
@@ -265,7 +265,7 @@ def _moe_apply_ep(params, x: jax.Array, cfg: ModelConfig, mesh
                 P("model", None, None), P("model", None, None))
     out_specs = (P(bspec, None, None), P())
     fn = shard_map(local, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     ew = params["experts"]
     y, aux = fn(x, params["router"], ew["w_gate"], ew["w_up"], ew["w_down"])
 
@@ -310,7 +310,7 @@ def ep2d_axes(mesh, num_experts: int):
 def _moe_apply_ep2d(params, x: jax.Array, cfg: ModelConfig, mesh
                     ) -> Tuple[jax.Array, jax.Array]:
     from jax.sharding import PartitionSpec as P  # noqa: PLC0415
-    from jax.experimental.shard_map import shard_map  # noqa: PLC0415
+    from jax import shard_map  # noqa: PLC0415
     from repro.parallel.sharding import dp_axes  # noqa: PLC0415
 
     m: MoEConfig = cfg.moe
@@ -386,7 +386,7 @@ def _moe_apply_ep2d(params, x: jax.Array, cfg: ModelConfig, mesh
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(bspec, None, None), P(), espec, espec, espec),
                    out_specs=(P(bspec, None, None), P()),
-                   check_rep=False)
+                   check_vma=False)
     ew = params["experts"]
     y, aux = fn(x, params["router"], ew["w_gate"], ew["w_up"], ew["w_down"])
 
@@ -414,7 +414,7 @@ def _moe_apply_ep_dstat(params, x: jax.Array, cfg: ModelConfig, mesh
     weight all-gathers on dbrx decode).
     """
     from jax.sharding import PartitionSpec as P  # noqa: PLC0415
-    from jax.experimental.shard_map import shard_map  # noqa: PLC0415
+    from jax import shard_map  # noqa: PLC0415
     from repro.parallel.sharding import dp_axes  # noqa: PLC0415
 
     m: MoEConfig = cfg.moe
@@ -498,7 +498,7 @@ def _moe_apply_ep_dstat(params, x: jax.Array, cfg: ModelConfig, mesh
                    in_specs=(P(bspec, None, None), P(), espec_up, espec_up,
                              espec_dn),
                    out_specs=(P(bspec, None, None), P()),
-                   check_rep=False)
+                   check_vma=False)
     ew = params["experts"]
     y, aux = fn(x, params["router"], ew["w_gate"], ew["w_up"], ew["w_down"])
 

@@ -34,7 +34,7 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import autotune, resilience
@@ -269,7 +269,7 @@ def _sharded_call(nests: Sequence[LoopNest], tile_fn: Callable,
 
     out_specs = P() if mode == "reduce" else P(CORES_AXIS)
     fn = shard_map(per_core, mesh=the_mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+                   out_specs=out_specs, check_vma=False)
     return fn(*prepared)
 
 
@@ -446,7 +446,7 @@ def cluster_kernel2d(fn: Callable, args: Sequence[jax.Array], *,
     out_spec[out_dims[0]] = "rows"
     out_spec[out_dims[1]] = "cols"
     wrapped = shard_map(fn, mesh=mesh, in_specs=tuple(specs),
-                        out_specs=P(*out_spec), check_rep=False)
+                        out_specs=P(*out_spec), check_vma=False)
     return wrapped(*args)
 
 
@@ -500,5 +500,5 @@ def cluster_kernel(fn: Callable, args: Sequence[jax.Array], *,
     # the output rank never needs probing.
     out_specs = P() if reduce else P(*([None] * out_dim), CORES_AXIS)
     wrapped = shard_map(per_core, mesh=the_mesh, in_specs=tuple(specs),
-                        out_specs=out_specs, check_rep=False)
+                        out_specs=out_specs, check_vma=False)
     return wrapped(*args)

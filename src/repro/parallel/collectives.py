@@ -21,7 +21,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -70,7 +70,7 @@ def ring_matmul(x: jax.Array, w: jax.Array, mesh: Mesh, *,
         functools.partial(_ring_body, axis=axis), mesh=mesh,
         in_specs=(P(bspec, None), P(axis, None)),
         out_specs=P(bspec, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, w)
 
@@ -90,5 +90,5 @@ def reduce_scatter_matmul(x: jax.Array, w: jax.Array, mesh: Mesh, *,
 
     fn = shard_map(body, mesh=mesh,
                    in_specs=(P(bspec, axis), P(axis, None)),
-                   out_specs=P(bspec, None), check_rep=False)
+                   out_specs=P(bspec, None), check_vma=False)
     return fn(x, w)

@@ -138,6 +138,17 @@ class HealthCheck:  # attribute access only (settings(suppress_health_check=…)
     filter_too_much = None
 
 
+def example(*args, **kwargs):
+    """``@example`` — an explicit case ``given`` runs before any draw."""
+
+    def deco(fn):
+        fn._shim_examples = [(args, kwargs)] + list(
+            getattr(fn, "_shim_examples", []))
+        return fn
+
+    return deco
+
+
 def given(*arg_strategies, **kw_strategies):
     if arg_strategies and kw_strategies:
         raise TypeError("shim: mixing positional and keyword strategies")
@@ -151,6 +162,8 @@ def given(*arg_strategies, **kw_strategies):
             # Seed from the test's qualified name: deterministic across runs,
             # distinct across tests.
             rng = random.Random(zlib.crc32(fn.__qualname__.encode()))
+            for ex_args, ex_kwargs in getattr(fn, "_shim_examples", []):
+                fn(*args, *ex_args, **kwargs, **ex_kwargs)
             executed = 0
             for _ in range(max_examples):
                 try:

@@ -82,7 +82,7 @@ class TestCompressedAllReduce:
     def test_compressed_psum_close_to_exact(self):
         run_sub("""
             import jax, numpy as np, jax.numpy as jnp
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             from repro.launch.mesh import make_host_mesh
             from repro.optim import compress

@@ -118,3 +118,18 @@ class TestShimContract:
 
         with pytest.raises(AssertionError, match="rejected all"):
             prop()
+
+    def test_shim_example_runs_first(self):
+        # @example cases run before any draw, with the drawn argument's name
+        shim = _load_shim()
+        seen = []
+
+        @shim.given(x=shim.strategies.integers(5, 9))
+        @shim.example(x=1)
+        @shim.settings(max_examples=3)
+        def prop(x):
+            seen.append(x)
+
+        prop()
+        assert seen[0] == 1 and len(seen) == 4
+        assert all(5 <= x <= 9 for x in seen[1:])

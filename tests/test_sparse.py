@@ -503,3 +503,24 @@ class TestSparseBenchValidators:
         with open(path, "w") as f:
             f.write(json.dumps(old) + "\n")
         assert kb.validate_bench_history(path) == 1
+
+
+class TestDeclaredOffChip:
+    """Mosaic refuses the flattened-table gather: both kernels are declared.
+
+    Off the interpreter the streamed variant raises the named error before
+    lowering — never a quiet interpret run or a hand-off to ``ref``.
+    """
+
+    @pytest.mark.parametrize("name", ["spmv", "spmm"])
+    def test_mosaic_refusal_is_named(self, name):
+        from repro.kernels import registry
+
+        entry = registry.get(name)
+        assert entry.mosaic_refusal == sp.MOSAIC_REFUSAL
+        args, kw = entry.example(np.random.default_rng(0))
+        snap = dict(frontend.DISPATCH_STATS)
+        with pytest.raises(frontend.MosaicUnsupported,
+                           match="Only 2D gather is supported"):
+            entry.ssr(*args, interpret=False, **kw)
+        assert frontend.DISPATCH_STATS["builds"] == snap["builds"]

@@ -1,0 +1,189 @@
+"""Mosaic compiles of the main-path kernels for a described TPU v5e.
+
+The TPU compiler is installed even where no chip is attached: it compiles
+for a topology that is only described (``v5e:2x2``), and refuses what the
+chip's compiler would refuse — a kernel Mosaic cannot lower, more scoped
+VMEM than a kernel may use.  Each case compiles one kernel, called the way
+a user calls it (``registry.dispatch`` with ``ssr=True`` and
+``interpret=False``), at the widths ``chip_smoke.py`` runs on the chip
+(qwen3-14b layers, f32), and asserts that the compiled HLO holds the
+Mosaic kernel (``tpu_custom_call``).  Nothing runs, so these say nothing
+about results or times.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file.  The last test runs ``chip_smoke.py``'s phase functions
+at tiny sizes in interpret mode on the CPU.
+"""
+
+import importlib.util
+import os
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import lowering
+from repro.core.lowering import Schedule
+from repro.kernels import registry
+
+
+@pytest.fixture(scope="module")
+def sched_cache(tmp_path_factory):
+    """An empty schedule cache: every compile takes the default schedule."""
+    old = os.environ.get("REPRO_SCHEDULE_CACHE")
+    os.environ["REPRO_SCHEDULE_CACHE"] = str(tmp_path_factory.mktemp("sched"))
+    yield
+    if old is None:
+        del os.environ["REPRO_SCHEDULE_CACHE"]
+    else:
+        os.environ["REPRO_SCHEDULE_CACHE"] = old
+
+
+@pytest.fixture(scope="module")
+def topo(sched_cache):
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no TPU compiler"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without that chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, shapes, sharding) -> str:
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
+            for s in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _ssr(name, **kw):
+    return lambda *a: registry.dispatch(name, *a, ssr=True, interpret=False,
+                                        **kw)
+
+
+def _heads(fn, heads=40, kv_heads=8):
+    """vmap a single-head kernel over GQA query heads (K/V expanded)."""
+    group = heads // kv_heads
+
+    def run(q, k, v):
+        return jax.vmap(fn)(q, jnp.repeat(k, group, 0),
+                            jnp.repeat(v, group, 0))
+    return run
+
+
+#: (case, callable, operand shapes) — the chip_smoke.py main-path sizes.
+MAIN_PATH = [
+    ("gemm", _ssr("gemm"), [(2048, 5120), (5120, 17408)]),
+    ("attention", _heads(_ssr("attention", causal=True)),
+     [(40, 2048, 128), (8, 2048, 128), (8, 2048, 128)]),
+    ("gemv", _ssr("gemv"), [(5120, 17408), (17408,)]),
+    ("gemv_depth3", _ssr("gemv", schedule=Schedule(buffer_depth=3)),
+     [(5120, 17408), (17408,)]),
+    ("reduction", _ssr("reduction"), [(64 << 20,), (64 << 20,)]),
+    ("stencil1d", _ssr("stencil1d"), [((16 << 20) + 10,), (11,)]),
+    ("mlp_block", _ssr("mlp_block"),
+     [(65536, 128), (128, 128), (128,), (128, 128), (128,)]),
+]
+
+
+@pytest.mark.parametrize("case,fn,shapes", MAIN_PATH,
+                         ids=[c[0] for c in MAIN_PATH])
+def test_main_path_compiles_for_v5e(one_chip, case, fn, shapes):
+    before = lowering.DISPATCH_STATS["pipelined"]
+    text = _compiled_text(fn, shapes, one_chip)
+    assert "tpu_custom_call" in text
+    if case == "gemv_depth3":
+        # the N-deep DMA emitter, not a silent fall back to the sync path
+        assert lowering.DISPATCH_STATS["pipelined"] > before
+
+
+@pytest.mark.parametrize("name", ["scan", "bitonic"])
+def test_repaired_kernel_compiles_for_v5e(one_chip, name):
+    # Mosaic refused these before: scan's cumsum, bitonic's 3-D shape casts
+    args, kw = registry.get(name).example(np.random.default_rng(0))
+    text = _compiled_text(_ssr(name, **kw), [a.shape for a in args],
+                          one_chip)
+    assert "tpu_custom_call" in text
+
+
+# --------------------------------------------------------------------------
+# chip_smoke.py's phases at tiny sizes, interpreted on the CPU
+# --------------------------------------------------------------------------
+
+
+def _chip_smoke():
+    if "chip_smoke" not in sys.modules:
+        path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+        spec = importlib.util.spec_from_file_location("chip_smoke", path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules["chip_smoke"] = mod    # dataclasses resolve it by name
+        spec.loader.exec_module(mod)
+    return sys.modules["chip_smoke"]
+
+
+TINY_PHASES = {
+    "gemm": lambda cs: cs.phase_gemm(64, 256, 384, interpret=True),
+    "gemv": lambda cs: cs.phase_gemv(40, 384, interpret=True),
+    "gemv_depth3": lambda cs: cs.phase_gemv(64, 2048, depth=3,
+                                            interpret=True),
+    "attention": lambda cs: cs.phase_attention(4, 2, 128, 64,
+                                               interpret=True),
+    "reduction": lambda cs: cs.phase_dot(5000, interpret=True),
+    "stencil1d": lambda cs: cs.phase_stencil1d(3000, interpret=True),
+    "mlp_block": lambda cs: cs.phase_mlp_block(64, interpret=True),
+    "scan": lambda cs: cs.phase_example("scan", interpret=True),
+}
+
+
+@pytest.mark.parametrize("phase", sorted(TINY_PHASES))
+def test_chip_smoke_phase_tiny_interpret(sched_cache, phase):
+    res = TINY_PHASES[phase](_chip_smoke())
+    assert res.passed, res.line()
+    assert res.builds > 0 and not res.custom_call
+    if phase == "gemv_depth3":
+        assert "pipelined=True" in res.note
+
+
+# --------------------------------------------------------------------------
+# The persistent compile cache the entry points place
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("placed", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, placed):
+    from repro.launch import compile_cache
+
+    root = Path(__file__).resolve().parents[1]
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        if placed:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert compile_cache.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == was  # set nothing
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            got = compile_cache.enable_compile_cache()
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            ignored = (root / ".gitignore").read_text().split()
+            assert ".jax_cache/" in ignored
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
