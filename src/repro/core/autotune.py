@@ -328,7 +328,8 @@ class ScheduleCache:
             self.quarantine(key)
             self.stats["misses"] += 1
             return None
-        if doc.get("version") != SCHEDULE_CACHE_VERSION:
+        if not isinstance(doc, dict) or \
+                doc.get("version") != SCHEDULE_CACHE_VERSION:
             self.quarantine(key)         # version skew: never mis-parse
             self.stats["misses"] += 1
             return None

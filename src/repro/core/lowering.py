@@ -56,6 +56,7 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from . import agu
 from . import nest_analysis
@@ -1154,6 +1155,14 @@ def reset_dispatch_stats() -> None:
         DISPATCH_STATS[k] = 0
 
 
+def _named(pipeline: Callable, name: Optional[str]) -> Callable:
+    """``pipeline`` renamed ``name``, so ``jax.jit`` compiles it as
+    ``jit_<name>``; with no name it stays ``jit_pipeline``."""
+    if name is not None:
+        pipeline.__name__ = pipeline.__qualname__ = name
+    return pipeline
+
+
 def _body_key(body: Callable) -> Any:
     """Stable cache identity for a block body.
 
@@ -1231,7 +1240,8 @@ def _assemble_kernel(grid: Tuple[int, ...], policy: BlockPolicy,
                      out_dtype, part_shape: Optional[Tuple[int, ...]],
                      interpret: Optional[bool],
                      buffer_depth: int = 2,
-                     uniforms: Sequence[jax.ShapeDtypeStruct] = ()) -> Callable:
+                     uniforms: Sequence[jax.ShapeDtypeStruct] = (),
+                     name: Optional[str] = None) -> Callable:
     """Shared kernel assembler for single-nest and chained plans.
 
     ``compute(in_refs, link_refs)`` returns the per-step value; ``n_links``
@@ -1329,6 +1339,7 @@ def _assemble_kernel(grid: Tuple[int, ...], policy: BlockPolicy,
         interpret=interpret,
         dimension_semantics=semantics,
         buffer_depth=buffer_depth,
+        name=name,
     )
 
 
@@ -1341,7 +1352,8 @@ def _probe_part_shape(fn: Callable, in_shapes: Sequence[Tuple[int, ...]],
 
 def _build_kernel(lowered: LoweredPlan, body: Callable, mode: str,
                   out_dtype, interpret: Optional[bool],
-                  uniforms: Sequence[jax.ShapeDtypeStruct] = ()) -> Callable:
+                  uniforms: Sequence[jax.ShapeDtypeStruct] = (),
+                  name: Optional[str] = None) -> Callable:
     """Wrap a block-level ``body`` into a full ssr_pallas kernel.
 
     ``body(*stream_blocks, *uniform_arrays)`` — uniform refs trail the
@@ -1364,7 +1376,7 @@ def _build_kernel(lowered: LoweredPlan, body: Callable, mode: str,
                             interpret,
                             _depths_for(lowered.schedule,
                                         len(lowered.in_streams)),
-                            uniforms=uniforms)
+                            uniforms=uniforms, name=name)
 
 
 def _table_view_shape(shape: Tuple[int, ...]) -> Tuple[int, ...]:
@@ -1411,8 +1423,8 @@ def _halo_widen(parts, halo: HaloRead):
 
 def _build_nest_kernel(lowered: LoweredNest, body: Callable,
                        out_dtype, interpret: Optional[bool],
-                       tables: Sequence[jax.ShapeDtypeStruct] = ()
-                       ) -> Callable:
+                       tables: Sequence[jax.ShapeDtypeStruct] = (),
+                       name: Optional[str] = None) -> Callable:
     """Wrap a block-level ``body`` into a level-mapped ssr_pallas kernel.
 
     ``body(*read_blocks)`` returns the output block's partial for one grid
@@ -1576,6 +1588,7 @@ def _build_nest_kernel(lowered: LoweredNest, body: Callable,
         interpret=interpret,
         dimension_semantics=lowered.semantics,
         buffer_depth=depths,
+        name=name,
     )
 
 
@@ -1623,7 +1636,8 @@ def _chain_stage_shapes(lowered: LoweredChain, bodies: Sequence[Callable],
 
 def _build_chain_kernel(lowered: LoweredChain, bodies: Sequence[Callable],
                         mode: str, out_dtype,
-                        interpret: Optional[bool]) -> Callable:
+                        interpret: Optional[bool],
+                        name: Optional[str] = None) -> Callable:
     """Fuse all stage bodies into ONE Pallas kernel.
 
     Per grid step: stage 0 computes its block from its read streams; each
@@ -1659,7 +1673,8 @@ def _build_chain_kernel(lowered: LoweredChain, bodies: Sequence[Callable],
                             compute, n_links, mode, out_dtype, part_shape,
                             interpret,
                             _depths_for(lowered.schedule,
-                                        len(lowered.in_streams)))
+                                        len(lowered.in_streams)),
+                            name=name)
 
 
 def _dag_slots(dag: ChainDAG) -> Tuple[Dict[str, int], int]:
@@ -1816,7 +1831,8 @@ def ssr_call(nest: LoopNest, body: Callable[..., jax.Array],
              schedule: Optional[Schedule] = None,
              num_lanes: Optional[int] = None,
              interpret: Optional[bool] = None,
-             uniforms: Optional[Dict[str, jax.Array]] = None) -> jax.Array:
+             uniforms: Optional[Dict[str, jax.Array]] = None,
+             name: Optional[str] = None) -> jax.Array:
     """Execute a :class:`LoopNest` as a streamed Pallas kernel.
 
     ``body(*blocks)`` is the pure compute region: it receives one VMEM block
@@ -1857,14 +1873,21 @@ def ssr_call(nest: LoopNest, body: Callable[..., jax.Array],
     entry point (direct ``ssr_call``, ``NestKernel``, ``cluster_call``)
     resolves the same winner for the same problem, so they stay
     bit-identical to each other before and after a tuner commit.
+
+    ``name`` is the kernel's family (``"gemm"``): the built pipeline
+    compiles as ``jit_ssr_<name>`` around the Pallas kernel ``ssr_<name>``.
+    The schedule resolution runs in the profiler span ``ssr.lookup``, the
+    planning, lowering and kernel emission in ``ssr.lower``.
     """
     tuned_key: Optional[str] = None
     if schedule is None and policy is DEFAULT_POLICY:
         from . import autotune as _autotune
 
         try:
-            schedule = _autotune.lookup(nest, operands, mode=mode,
-                                        out_dtype=str(jnp.dtype(out_dtype)))
+            with TraceAnnotation("ssr.lookup"):
+                schedule = _autotune.lookup(
+                    nest, operands, mode=mode,
+                    out_dtype=str(jnp.dtype(out_dtype)))
         except resilience.fallback_error_types() as e:
             _record_fallback("ssr_call", e, from_schedule="tuned-lookup",
                              to_schedule="default")
@@ -1875,7 +1898,8 @@ def ssr_call(nest: LoopNest, body: Callable[..., jax.Array],
                     nest, operands, mode=mode,
                     out_dtype=str(jnp.dtype(out_dtype)))
     num_lanes = nest_analysis.auto_lanes(nest, num_lanes)
-    plan = _plan_for(nest, num_lanes)
+    with TraceAnnotation("ssr.lower"):
+        plan = _plan_for(nest, num_lanes)
     has_output = any(r.kind == Direction.WRITE for r in nest.refs)
     if has_output:
         mode = "nest"          # the output ref, not the mode, shapes the call
@@ -1886,7 +1910,8 @@ def ssr_call(nest: LoopNest, body: Callable[..., jax.Array],
             "(explicit WRITE ref) path; use a map/reduce nest")
     DISPATCH_STATS["calls"] += 1
 
-    def _dispatch(sched: Schedule) -> jax.Array:
+    def _kernel(sched: Schedule):
+        """The cached or newly built pipeline, and its operands."""
         lowered = _lowered_for(plan, sched, has_output)
         gathers = lowered.gathers if has_output else ()
         missing = [s.name for s in lowered.in_streams
@@ -1901,39 +1926,46 @@ def ssr_call(nest: LoopNest, body: Callable[..., jax.Array],
                               _table_view_shape(tuple(operands[g.name]
                                                       .shape)))
                   for g in gathers]
+        args = (*arrays, *tables, *[a for _, a in uni])
         key = (nest, sched, mode, _body_key(body), str(jnp.dtype(out_dtype)),
                tuple((tuple(a.shape), str(a.dtype)) for a in arrays + tables),
-               _uniform_sig(uni), num_lanes, interpret)
+               _uniform_sig(uni), num_lanes, interpret, name)
         fn = _kernel_cache_get(key)
-        if fn is None:
+        if fn is not None:
+            return fn, args
+        if has_output:
+            kernel = _build_nest_kernel(
+                lowered, body, jnp.dtype(out_dtype), interpret,
+                tables=tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
+                             for a in tables), name=name)
+        else:
+            kernel = _build_kernel(
+                lowered, body, mode, jnp.dtype(out_dtype), interpret,
+                uniforms=tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
+                               for _, a in uni), name=name)
+
+        def pipeline(*arrs, _lowered=lowered, _kernel=kernel,
+                     _sched=sched):
+            DISPATCH_STATS["traces"] += 1   # moves only while tracing
+            ns = len(_lowered.in_streams)
+            prepared = [s.prepare(a)
+                        for s, a in zip(_lowered.in_streams, arrs[:ns])]
+            out = _kernel(*prepared, *arrs[ns:])
             if has_output:
-                kernel = _build_nest_kernel(
-                    lowered, body, jnp.dtype(out_dtype), interpret,
-                    tables=tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
-                                 for a in tables))
-            else:
-                kernel = _build_kernel(
-                    lowered, body, mode, jnp.dtype(out_dtype), interpret,
-                    uniforms=tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
-                                   for _, a in uni))
+                return _trim_nest_output(out, _lowered)
+            return _trim_output(out, nest.bounds, mode, _sched.policy)
 
-            def pipeline(*arrs, _lowered=lowered, _kernel=kernel,
-                         _sched=sched):
-                DISPATCH_STATS["traces"] += 1   # moves only while tracing
-                ns = len(_lowered.in_streams)
-                prepared = [s.prepare(a)
-                            for s, a in zip(_lowered.in_streams, arrs[:ns])]
-                out = _kernel(*prepared, *arrs[ns:])
-                if has_output:
-                    return _trim_nest_output(out, _lowered)
-                return _trim_output(out, nest.bounds, mode, _sched.policy)
+        resilience.inject("compile")
+        fn = jax.jit(_named(pipeline, name and f"ssr_{name}"))
+        DISPATCH_STATS["builds"] += 1
+        DISPATCH_STATS["pipelined"] += int(kernel.pipelined)
+        _kernel_cache_put(key, fn)
+        return fn, args
 
-            resilience.inject("compile")
-            fn = jax.jit(pipeline)
-            DISPATCH_STATS["builds"] += 1
-            DISPATCH_STATS["pipelined"] += int(kernel.pipelined)
-            _kernel_cache_put(key, fn)
-        return fn(*arrays, *tables, *[a for _, a in uni])
+    def _dispatch(sched: Schedule) -> jax.Array:
+        with TraceAnnotation("ssr.lower"):
+            fn, args = _kernel(sched)
+        return fn(*args)
 
     if tuned_key is None:
         return _dispatch(_resolve_schedule(policy, schedule))
@@ -1968,7 +2000,8 @@ def ssr_chain_call(nests: Sequence[LoopNest],
                    policy: BlockPolicy = DEFAULT_POLICY,
                    schedule: Optional[Schedule] = None,
                    num_lanes: Optional[int] = None,
-                   interpret: Optional[bool] = None) -> jax.Array:
+                   interpret: Optional[bool] = None,
+                   name: Optional[str] = None) -> jax.Array:
     """Execute a producer→consumer chain of nests as ONE Pallas kernel.
 
     ``nests[k]`` and ``nests[k+1]`` must be chainable (see
@@ -1983,7 +2016,8 @@ def ssr_chain_call(nests: Sequence[LoopNest],
     same contract as :func:`ssr_call` — including the vectorised reduce
     accumulator when the last body returns a block-shaped partial.  Reduce
     bodies must be padding-neutral at every stage: the padded tail flows
-    through the whole chain.
+    through the whole chain.  ``name`` and the profiler spans as in
+    :func:`ssr_call`.
     """
     nests = tuple(nests)
     bodies = tuple(bodies)
@@ -1998,8 +2032,10 @@ def ssr_chain_call(nests: Sequence[LoopNest],
         from . import autotune as _autotune
 
         try:
-            schedule = _autotune.lookup(nests[0], operands, mode=mode,
-                                        out_dtype=str(jnp.dtype(out_dtype)))
+            with TraceAnnotation("ssr.lookup"):
+                schedule = _autotune.lookup(
+                    nests[0], operands, mode=mode,
+                    out_dtype=str(jnp.dtype(out_dtype)))
         except resilience.fallback_error_types() as e:
             _record_fallback("ssr_chain_call", e,
                              from_schedule="tuned-lookup",
@@ -2010,10 +2046,12 @@ def ssr_chain_call(nests: Sequence[LoopNest],
                 tuned_key = _autotune.cache_key(
                     nests[0], operands, mode=mode,
                     out_dtype=str(jnp.dtype(out_dtype)))
-    chained = _chain_for(nests, num_lanes)
+    with TraceAnnotation("ssr.lower"):
+        chained = _chain_for(nests, num_lanes)
     DISPATCH_STATS["calls"] += 1
 
-    def _dispatch(sched: Schedule) -> jax.Array:
+    def _kernel(sched: Schedule):
+        """The cached or newly built pipeline, and its operands."""
         lowered = _lowered_chain_for(chained, sched)
         flat = lowered.in_streams
         missing = sorted({s.name for s in flat} - set(operands))
@@ -2023,24 +2061,31 @@ def ssr_chain_call(nests: Sequence[LoopNest],
         key = ("chain", nests, sched, mode,
                tuple(_body_key(b) for b in bodies), str(jnp.dtype(out_dtype)),
                tuple((tuple(a.shape), str(a.dtype)) for a in arrays),
-               num_lanes, interpret)
+               num_lanes, interpret, name)
         fn = _kernel_cache_get(key)
-        if fn is None:
-            kernel = _build_chain_kernel(lowered, bodies, mode,
-                                         jnp.dtype(out_dtype), interpret)
+        if fn is not None:
+            return fn, arrays
+        kernel = _build_chain_kernel(lowered, bodies, mode,
+                                     jnp.dtype(out_dtype), interpret,
+                                     name=name)
 
-            def pipeline(*arrs, _lowered=lowered, _kernel=kernel,
-                         _sched=sched):
-                DISPATCH_STATS["traces"] += 1   # moves only while tracing
-                prepared = [s.prepare(a)
-                            for s, a in zip(_lowered.in_streams, arrs)]
-                out = _kernel(*prepared)
-                return _trim_output(out, chained.bounds, mode, _sched.policy)
+        def pipeline(*arrs, _lowered=lowered, _kernel=kernel,
+                     _sched=sched):
+            DISPATCH_STATS["traces"] += 1   # moves only while tracing
+            prepared = [s.prepare(a)
+                        for s, a in zip(_lowered.in_streams, arrs)]
+            out = _kernel(*prepared)
+            return _trim_output(out, chained.bounds, mode, _sched.policy)
 
-            resilience.inject("compile")
-            fn = jax.jit(pipeline)
-            DISPATCH_STATS["builds"] += 1
-            _kernel_cache_put(key, fn)
+        resilience.inject("compile")
+        fn = jax.jit(_named(pipeline, name and f"ssr_{name}"))
+        DISPATCH_STATS["builds"] += 1
+        _kernel_cache_put(key, fn)
+        return fn, arrays
+
+    def _dispatch(sched: Schedule) -> jax.Array:
+        with TraceAnnotation("ssr.lower"):
+            fn, arrays = _kernel(sched)
         return fn(*arrays)
 
     if tuned_key is None:

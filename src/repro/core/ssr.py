@@ -211,6 +211,7 @@ def _pipelined_call(
     buffer_depth: Tuple[int, ...],
     interpret: bool,
     extra_kwargs: dict,
+    name: Optional[str] = None,
 ) -> Callable[..., Any]:
     """Emit the explicit N-deep HBM→VMEM rotation (pipelined emission).
 
@@ -332,6 +333,7 @@ def _pipelined_call(
             else out_shapes[0],
             scratch_shapes=rot + dma_sems + list(scratch_shapes),
             interpret=interpret,
+            name=name,
             **extra_kwargs,
         )
         return call(*arrays)
@@ -352,6 +354,7 @@ def ssr_pallas(
     validate: bool = True,
     cost_estimate: Optional[pl.CostEstimate] = None,
     buffer_depth=DEFAULT_BUFFER_DEPTH,
+    name: Optional[str] = None,
 ) -> Callable[..., Any]:
     """Build a streamed Pallas kernel from SSR-style block streams.
 
@@ -371,7 +374,11 @@ def ssr_pallas(
     the *requested* depths (:func:`stream_vmem_bytes`) — each input at its
     own depth, outputs at the maximum — so a schedule legal here is legal
     on the deepest path it might take.
+
+    ``name`` is the kernel's family (``"gemm"``): the Pallas kernel is
+    named ``ssr_<name>`` in the lowered program and on the device's trace.
     """
+    kernel_name = None if name is None else f"ssr_{name}"
     for s in in_streams:
         if s.direction != Direction.READ:
             raise ValueError(f"input stream '{s.name}' must be a read stream")
@@ -425,7 +432,7 @@ def ssr_pallas(
             body, grid=grid, in_streams=in_streams,
             out_streams=out_streams, out_shapes=out_shapes,
             scratch_shapes=scratch_shapes, buffer_depth=depths,
-            interpret=interpret, extra_kwargs=kwargs)
+            interpret=interpret, extra_kwargs=kwargs, name=kernel_name)
     else:
         call = pl.pallas_call(
             body,
@@ -438,6 +445,7 @@ def ssr_pallas(
             else out_shapes[0],
             scratch_shapes=list(scratch_shapes),
             interpret=interpret,
+            name=kernel_name,
             **kwargs,
         )
 

@@ -49,18 +49,21 @@ mandatory — an undeclared escape hatch is a compiler-coverage bug.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import inspect
+import time
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.profiler import TraceAnnotation
 
 from repro.core import BlockStream  # noqa: F401  (re-export for kernels)
 from repro.core import autotune, resilience
 from repro.core.lowering import (DEFAULT_SCHEDULE, Schedule, _body_key,
-                                 ssr_call)
+                                 _named, ssr_call)
 from repro.core.ssr import _on_tpu, ssr_pallas
 
 ROWS = 8
@@ -75,8 +78,13 @@ COMPUTE_DTYPE = jnp.float32
 #: call is a pure cache hit.  ``fallbacks``/``degraded`` mirror the
 #: lowering counters: lookups abandoned for the default schedule vs tuned
 #: pipelines quarantined and rebuilt on the default — zero when healthy.
+#: ``build_ns`` is the wall time, in integer nanoseconds, spent in
+#: ``ssr.build`` spans (a cache miss up to the return of the new pipeline's
+#: first call: prepare, lookup, lowering, trace, compile); the lowering
+#: layer's builds run inside them and are not counted again.
 DISPATCH_STATS: Dict[str, int] = {"builds": 0, "traces": 0, "calls": 0,
-                                  "fallbacks": 0, "degraded": 0}
+                                  "fallbacks": 0, "degraded": 0,
+                                  "build_ns": 0}
 
 
 #: Built-pipeline cap per kernel instance: epoch bumps retire old entries,
@@ -87,6 +95,18 @@ _PIPELINE_CACHE_MAX = 512
 def reset_dispatch_stats() -> None:
     for k in DISPATCH_STATS:
         DISPATCH_STATS[k] = 0
+
+
+@contextlib.contextmanager
+def _build_span():
+    """The ``ssr.build`` profiler span of a pipeline-cache miss, timed into
+    ``DISPATCH_STATS["build_ns"]`` whether or not a profiler runs."""
+    t0 = time.perf_counter_ns()
+    try:
+        with TraceAnnotation("ssr.build"):
+            yield
+    finally:
+        DISPATCH_STATS["build_ns"] += time.perf_counter_ns() - t0
 
 
 class MosaicUnsupported(NotImplementedError):
@@ -194,9 +214,14 @@ class _KernelBase:
     plus one jitted invocation.
     """
 
+    #: Prefix of the kernel's name on the device (``ssr_gemv``): streamed.
+    _prefix = "ssr"
+
     def __init__(self, name: str, *, prepare: Callable,
                  finish: Optional[Callable] = None):
         self.name = name
+        #: The name of the pipeline's program and of its Pallas kernel.
+        self.kernel_name = f"{self._prefix}_{name}"
         self._prepare = prepare
         self._finish = finish
         self._cache: Dict[Any, Callable] = {}
@@ -225,7 +250,10 @@ class _KernelBase:
             {k: v for k, v in params.items() if k != "schedule"}
         key = (_call_key(args, params), bool(interpret))
         fn = self._cache.get(key)
-        if fn is None:
+        if fn is not None:
+            with TraceAnnotation("ssr.launch"):
+                return fn(*[a for a in args if _is_arraylike(a)])
+        with _build_span():
             arrays, static, _final = self._prepare(*args, **prep_params)
             built = self._build(static, tuple(arrays), bool(interpret),
                                 schedule)
@@ -245,12 +273,12 @@ class _KernelBase:
                 out = _built(*prepared)
                 return self._finish(out, final) if self._finish else out
 
-            fn = jax.jit(pipeline)
+            fn = jax.jit(_named(pipeline, self.kernel_name))
             DISPATCH_STATS["builds"] += 1
             if len(self._cache) >= _PIPELINE_CACHE_MAX:
                 self._cache.clear()
             self._cache[key] = fn
-        return fn(*[a for a in args if _is_arraylike(a)])
+            return fn(*[a for a in args if _is_arraylike(a)])
 
 
 def _require_waiver(name: str, waiver: Optional[str]) -> str:
@@ -382,7 +410,10 @@ class NestKernel:
         key = (_call_key(args, params), schedule, interpret,
                autotune.epoch() if schedule is None else -1)
         fn = self._cache.get(key)
-        if fn is None:
+        if fn is not None:
+            with TraceAnnotation("ssr.launch"):
+                return fn(*[a for a in args if _is_arraylike(a)])
+        with _build_span():
             operands, static, _final = self._prepare(*args, **params)
             nest = self._nest(static)
             kw = {} if self._out_dtype is None else \
@@ -391,9 +422,10 @@ class NestKernel:
             if sched is None:
                 out_dtype = str(jnp.dtype(kw.get("out_dtype", jnp.float32)))
                 try:
-                    sched = autotune.lookup(nest, dict(operands),
-                                            mode=self._mode,
-                                            out_dtype=out_dtype)
+                    with TraceAnnotation("ssr.lookup"):
+                        sched = autotune.lookup(nest, dict(operands),
+                                                mode=self._mode,
+                                                out_dtype=out_dtype)
                 except resilience.fallback_error_types() as e:
                     DISPATCH_STATS["fallbacks"] += 1
                     resilience.record_fallback(
@@ -417,16 +449,16 @@ class NestKernel:
                     {"out_dtype": self._out_dtype(s)}
                 out = ssr_call(self._nest(s), self._body(s), dict(ops),
                                mode=self._mode, schedule=_sched,
-                               interpret=interpret, **okw)
+                               interpret=interpret, name=self.name, **okw)
                 return self._finish(out, final) if self._finish else out
 
             resilience.inject("compile")
-            fn = jax.jit(pipeline)
+            fn = jax.jit(_named(pipeline, f"ssr_{self.name}"))
             DISPATCH_STATS["builds"] += 1
             if len(self._cache) >= _PIPELINE_CACHE_MAX:
                 self._cache.clear()
             self._cache[key] = fn
-        return fn(*[a for a in args if _is_arraylike(a)])
+            return fn(*[a for a in args if _is_arraylike(a)])
 
 
 class StreamKernel(_KernelBase):
@@ -457,11 +489,14 @@ class StreamKernel(_KernelBase):
             interpret=interpret,
             dimension_semantics=lc.dimension_semantics,
             buffer_depth=(schedule or DEFAULT_SCHEDULE).buffer_depth,
+            name=self.name,
         )
 
 
 class MonolithicKernel(_KernelBase):
     """A baseline kernel: one grid step, explicit in-body block walk."""
+
+    _prefix = "baseline"
 
     def __init__(self, name: str, *, prepare: Callable, body: Callable,
                  out_shape: Callable, finish: Optional[Callable] = None):
@@ -477,6 +512,7 @@ class MonolithicKernel(_KernelBase):
             self._body(static),
             out_shape=self._out_shape(static, *arrays),
             interpret=interpret,
+            name=self.kernel_name,
         )
         return jax.jit(call)
 
@@ -545,4 +581,5 @@ class ChainedKernel(_KernelBase):
             interpret=interpret,
             dimension_semantics=lc.dimension_semantics,
             buffer_depth=(schedule or DEFAULT_SCHEDULE).buffer_depth,
+            name=self.name,
         )

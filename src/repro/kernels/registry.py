@@ -31,6 +31,8 @@ import importlib
 import os
 from typing import Callable, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.core import resilience
 from repro.core.region import ssr_enabled
 
@@ -142,7 +144,9 @@ def dispatch(name: str, *args, ssr: Optional[bool] = None,
     """Run a kernel through the ``ssrcfg`` gate (paper §2.2.2).
 
     ``ssr=None`` consults :func:`region.ssr_enabled`; semantics are identical
-    either way — only the execution engine changes.
+    either way — only the execution engine changes.  A streamed call runs
+    inside the profiler span ``ssr.call``; inside it the kernel frontend
+    opens ``ssr.launch`` (a cached pipeline) or ``ssr.build`` (a new one).
 
     ``baseline_fallback`` is the last rung of the degradation ladder
     (tuned → default schedule → baseline): when the streamed variant fails
@@ -155,17 +159,19 @@ def dispatch(name: str, *args, ssr: Optional[bool] = None,
     engine in exchange for availability; genuine numerics/user errors
     (``TypeError``, shape ``ValueError``…) always propagate.
     """
-    entry = get(name)
     use = ssr_enabled() if ssr is None else ssr
-    fn = entry.ssr if use else entry.ref
-    if baseline_fallback is None:
-        baseline_fallback = _baseline_fallback_enabled()
-    if not (use and baseline_fallback):
-        return fn(*args, **kwargs)
-    try:
-        return fn(*args, **kwargs)
-    except resilience.fallback_error_types() as e:
-        resilience.record_fallback(
-            seam=resilience.classify(e), site=f"registry:{name}", error=e,
-            from_schedule="ssr", to_schedule="baseline")
-        return entry.ref(*args, **kwargs)
+    if not use:
+        return get(name).ref(*args, **kwargs)
+    with TraceAnnotation("ssr.call"):
+        entry = get(name)
+        if baseline_fallback is None:
+            baseline_fallback = _baseline_fallback_enabled()
+        if not baseline_fallback:
+            return entry.ssr(*args, **kwargs)
+        try:
+            return entry.ssr(*args, **kwargs)
+        except resilience.fallback_error_types() as e:
+            resilience.record_fallback(
+                seam=resilience.classify(e), site=f"registry:{name}",
+                error=e, from_schedule="ssr", to_schedule="baseline")
+            return entry.ref(*args, **kwargs)

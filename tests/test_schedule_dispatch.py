@@ -228,6 +228,113 @@ class TestZeroOverheadDispatch:
         assert frontend.DISPATCH_STATS["builds"] == b1 + 1
 
 
+class _Spans:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records every span
+    opened as ``(name, enclosing span's name)``."""
+
+    def __init__(self):
+        self.opened = []
+        self._stack = []
+
+    def __call__(self, name, **_kw):
+        rec = self
+
+        class _Span:
+            def __enter__(self):
+                rec.opened.append((name, rec._stack[-1] if rec._stack
+                                   else None))
+                rec._stack.append(name)
+
+            def __exit__(self, *exc):
+                rec._stack.pop()
+
+        return _Span()
+
+
+class TestDispatchSpans:
+    """The profiler spans of the dispatch path and the ``build_ns``
+    counter: a warm call opens ``ssr.call`` ⊃ ``ssr.launch`` and moves
+    ``calls`` only; a cold one opens ``ssr.call`` ⊃ ``ssr.build`` ⊃
+    ``ssr.lookup``/``ssr.lower`` and moves ``build_ns``."""
+
+    @pytest.fixture
+    def spans(self, monkeypatch, tmp_path):
+        from repro.kernels import registry
+
+        monkeypatch.setenv("REPRO_SCHEDULE_CACHE", str(tmp_path))
+        rec = _Spans()
+        for mod in (registry, frontend, lowering):
+            monkeypatch.setattr(mod, "TraceAnnotation", rec)
+        return rec
+
+    # data of their own: drawing from the module's RNG would change every
+    # later test's inputs
+    rng = np.random.default_rng(14)
+
+    @classmethod
+    def arr(cls, shape):
+        return jnp.asarray(cls.rng.standard_normal(shape), jnp.float32)
+
+    CALLS = {
+        "matmul": lambda t, ops, n: ops.matmul(t.arr((n, 96)),
+                                               t.arr((96, 160)), ssr=True),
+        "gemv": lambda t, ops, n: ops.gemv(t.arr((n, 256)), t.arr(256),
+                                           ssr=True),
+    }
+
+    @pytest.mark.parametrize("op", sorted(CALLS))
+    def test_warm_call_opens_call_and_launch_only(self, spans, op):
+        from repro.kernels import ops
+
+        self.CALLS[op](self, ops, 40)            # warm the pipeline
+        stats = dict(frontend.DISPATCH_STATS)
+        low = dict(lowering.DISPATCH_STATS)
+        spans.opened.clear()
+        self.CALLS[op](self, ops, 40)
+        assert spans.opened == [("ssr.call", None),
+                                ("ssr.launch", "ssr.call")]
+        moved = {k for k, v in frontend.DISPATCH_STATS.items()
+                 if v != stats[k]}
+        assert moved == {"calls"}
+        assert frontend.DISPATCH_STATS["calls"] == stats["calls"] + 1
+        assert dict(lowering.DISPATCH_STATS) == low
+        assert frontend.DISPATCH_STATS["build_ns"] == stats["build_ns"]
+
+    @pytest.mark.parametrize("op", sorted(CALLS))
+    def test_cold_call_opens_build_lookup_and_lower(self, spans, op):
+        from repro.kernels import ops
+
+        before = frontend.DISPATCH_STATS["build_ns"]
+        self.CALLS[op](self, ops, 56)            # a shape no test used
+        assert spans.opened[:2] == [("ssr.call", None),
+                                    ("ssr.build", "ssr.call")]
+        inner = set(spans.opened[2:])
+        assert ("ssr.lookup", "ssr.build") in inner
+        assert ("ssr.lower", "ssr.build") in inner
+        assert {n for n, _ in inner} == {"ssr.lookup", "ssr.lower"}
+        assert frontend.DISPATCH_STATS["build_ns"] > before
+
+    def test_call_under_vmap_opens_one_call_span(self, spans):
+        import jax
+        from repro.kernels import ops
+
+        attn = jax.vmap(lambda q, k, v: ops.flash_attention(
+            q, k, v, causal=True, ssr=True))
+        q, k, v = (self.arr((2, 64, 64)) for _ in range(3))
+        attn(q, k, v)
+        assert [n for n, _ in spans.opened].count("ssr.call") == 1
+        spans.opened.clear()
+        attn(q, k, v)
+        assert spans.opened == [("ssr.call", None),
+                                ("ssr.launch", "ssr.call")]
+
+    def test_unstreamed_call_opens_no_span(self, spans):
+        from repro.kernels import ops
+
+        ops.gemv(self.arr((40, 256)), self.arr(256), ssr=False)
+        assert spans.opened == []
+
+
 class TestTransparentTuning:
     """NestKernel + cluster pick up committed winners without code changes."""
 

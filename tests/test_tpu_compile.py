@@ -68,10 +68,14 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compiled_text(fn, shapes, sharding) -> str:
+def _lowered(fn, shapes, sharding):
     args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
             for s in shapes]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    return jax.jit(fn).lower(*args)
+
+
+def _compiled_text(fn, shapes, sharding) -> str:
+    return _lowered(fn, shapes, sharding).compile().as_text()
 
 
 def _ssr(name, **kw):
@@ -104,15 +108,32 @@ MAIN_PATH = [
 ]
 
 
+#: The kernel family of each main-path case compiled through NestKernel.
+FAMILY = {"gemm": "gemm", "attention": "attention", "gemv": "gemv",
+          "gemv_depth3": "gemv", "reduction": "reduction",
+          "stencil1d": "stencil1d"}
+
+
 @pytest.mark.parametrize("case,fn,shapes", MAIN_PATH,
                          ids=[c[0] for c in MAIN_PATH])
 def test_main_path_compiles_for_v5e(one_chip, case, fn, shapes):
     before = lowering.DISPATCH_STATS["pipelined"]
-    text = _compiled_text(fn, shapes, one_chip)
+    lowered = _lowered(fn, shapes, one_chip)
+    text = lowered.compile().as_text()
     assert "tpu_custom_call" in text
     if case == "gemv_depth3":
         # the N-deep DMA emitter, not a silent fall back to the sync path
         assert lowering.DISPATCH_STATS["pipelined"] > before
+    if case in FAMILY:
+        # the jitted pipeline and its Mosaic kernel carry the family's
+        # name, so a device trace shows jit_ssr_<family> and %ssr_<family>
+        name = f"ssr_{FAMILY[case]}"
+        assert f"func.func private @{name}(" in lowered.as_text()
+        kernels = [ln.split("=", 1)[0].strip() for ln in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in ln]
+        assert kernels
+        assert all(k.removeprefix("ROOT ").startswith(f"%{name}.")
+                   for k in kernels), kernels
 
 
 @pytest.mark.parametrize("name", ["scan", "bitonic"])
@@ -153,8 +174,24 @@ TINY_PHASES = {
 }
 
 
+@pytest.fixture
+def cold_kernels():
+    """Every kernel's pipeline cache empty, as in ``chip_smoke.py``'s own
+    fresh process: a phase must build what it runs, whatever ran earlier
+    in this test process."""
+    from repro.kernels import frontend
+
+    registry.names()                     # imports every kernel module
+    for mod in list(sys.modules.values()):
+        if getattr(mod, "__name__", "").startswith("repro.kernels."):
+            for v in vars(mod).values():
+                if isinstance(v, (frontend._KernelBase, frontend.NestKernel)):
+                    v._cache.clear()
+    lowering.clear_caches()
+
+
 @pytest.mark.parametrize("phase", sorted(TINY_PHASES))
-def test_chip_smoke_phase_tiny_interpret(sched_cache, phase):
+def test_chip_smoke_phase_tiny_interpret(sched_cache, cold_kernels, phase):
     res = TINY_PHASES[phase](_chip_smoke())
     assert res.passed, res.line()
     assert res.builds > 0 and not res.custom_call
