@@ -33,13 +33,16 @@ Dispatch integration: ``kernels.frontend.NestKernel`` and
 ``parallel.cluster.cluster_call`` consult :func:`lookup` when no explicit
 schedule is passed, so ``ops.py`` callers get tuned schedules transparently
 once a cache entry exists; :func:`epoch` lets their built-pipeline caches
-invalidate when the tuner commits a new winner.
+invalidate when the tuner commits a new winner.  On a miss, a plain
+contraction nest (GEMM) gets the tiles that its modelled HBM traffic
+favours (:func:`traffic_schedule`), and everything else the default.
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import hashlib
 import itertools
 import json
@@ -54,7 +57,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 from . import nest_analysis, resilience
 from .compiler import ChainDAG, LoopNest, _fused_region_count, ssrify
 from .lowering import (DEFAULT_SCHEDULE, LoweredChain, LoweredNest,
-                       LoweredPlan, LoweringError, Schedule, _plan_for)
+                       LoweredPlan, LoweringError, Schedule, _plan_for,
+                       lower_nest)
 from .nest_analysis import auto_lanes
 from .ssr import (DEFAULT_BUFFER_DEPTH, MAX_BUFFER_DEPTH, VMEM_BUDGET_BYTES,
                   stream_vmem_bytes)
@@ -461,19 +465,166 @@ def _bump_epoch() -> None:
     _EPOCH += 1
 
 
+def resolve(nest: LoopNest, operands: Dict[str, Any], *,
+            mode: str = "reduce", out_dtype: str = "float32",
+            cores: int = 1,
+            cache: Optional[ScheduleCache] = None) -> Tuple[Schedule, str]:
+    """``(schedule, source)``: the committed tuned winner (``"tuned"``),
+    else the tiles the HBM-traffic model picks for a plain contraction
+    (``"traffic"``, :func:`traffic_schedule`), else ``DEFAULT_SCHEDULE``
+    (``"default"``).  It never measures: one dict/file probe, and on a
+    miss one memoised model evaluation per (nest, shapes)."""
+    cache = cache or global_cache()
+    key = cache_key(nest, operands, mode=mode, out_dtype=str(out_dtype),
+                    cores=cores)
+    tuned = cache.get(key)
+    if tuned is not None:
+        return tuned, "tuned"
+    picked = traffic_schedule(nest, operands, out_dtype=str(out_dtype))
+    if picked is not None:
+        return picked, "traffic"
+    return DEFAULT_SCHEDULE, "default"
+
+
 def lookup(nest: LoopNest, operands: Dict[str, Any], *,
            mode: str = "reduce", out_dtype: str = "float32",
            cores: int = 1,
            cache: Optional[ScheduleCache] = None) -> Schedule:
-    """Cache-only schedule resolution: tuned winner or the default.
+    """Cache-only schedule resolution: the schedule of :func:`resolve`.
 
     This is the transparent-dispatch hook — it never measures, so calling
     it on every kernel build costs one dict/file probe.
     """
-    cache = cache or global_cache()
-    key = cache_key(nest, operands, mode=mode, out_dtype=str(out_dtype),
-                    cores=cores)
-    return cache.get(key) or DEFAULT_SCHEDULE
+    return resolve(nest, operands, mode=mode, out_dtype=out_dtype,
+                   cores=cores, cache=cache)[0]
+
+
+# --------------------------------------------------------------------------
+# Traffic-chosen tiles: a plain contraction's schedule on a cache miss
+# --------------------------------------------------------------------------
+
+#: A traffic-chosen schedule replaces the default only where it saves at
+#: least this share of the default's modelled HBM bytes.  A GEMV streams its
+#: matrix once under any tiling; kilobytes of re-read vector do not pay for
+#: new tiles.
+TRAFFIC_MIN_SAVING = 0.125
+
+
+def _fetches(levels: Tuple[int, ...], lowered: LoweredNest) -> int:
+    """Grid steps on which a stream's block index changes.
+
+    Pallas fetches a block only when its index differs from the previous
+    step's, and the grid walks row-major: the index changes at every step
+    of the grid axes up to the innermost one it varies with.
+    """
+    axes = [k for k, lvl in enumerate(lowered.axis_order)
+            if lvl in levels and lowered.grid[k] > 1]
+    return math.prod(lowered.grid[:max(axes) + 1]) if axes else 1
+
+
+def traffic_bytes(lowered: LoweredNest,
+                  itemsizes: Dict[str, int]) -> int:
+    """Modelled HBM bytes of one call of a level-mapped kernel.
+
+    For every stream, its block bytes times the grid steps on which its
+    block index changes (:func:`_fetches`); the output's block changes once
+    per output block, so it is written once.  For a GEMM with the
+    contraction innermost and split, that is A·⌈N/tn⌉ + B·⌈M/tm⌉ + C.
+    ``itemsizes`` maps stream names to bytes per element (4 if absent).
+    """
+    return sum(math.prod(s.stream.block_shape) * itemsizes.get(s.name, 4)
+               * _fetches(s.levels, lowered)
+               for s in (*lowered.in_streams, lowered.out_stream))
+
+
+def _is_plain_contraction(lowered) -> bool:
+    """An output accumulator revisited across trailing contraction levels,
+    with no online-softmax rescale, gather or halo."""
+    return (isinstance(lowered, LoweredNest) and bool(lowered.contraction_axes)
+            and not lowered.rescale and not lowered.gathers
+            and not lowered.halos)
+
+
+def _aligned_factors(bounds: Sequence[int], align: int,
+                     least: int) -> List[int]:
+    """Tile factors (in units of ``align``) giving every distinct tile of
+    these padded bounds at or above ``least`` units: a level's tile is its
+    largest aligned divisor under the target, so only divisors matter."""
+    out = {least}
+    for b in bounds:
+        units = b // align
+        for f in range(1, math.isqrt(units) + 1):
+            if units % f == 0:
+                out.update(g for g in (f, units // f) if g > least)
+    return sorted(out)
+
+
+def traffic_schedule(nest: LoopNest, operands: Dict[str, Any], *,
+                     out_dtype: str = "float32") -> Optional[Schedule]:
+    """The tiles that least HBM traffic favours, for a plain contraction.
+
+    Candidates keep the default block geometry (rows 8, lanes 128) and vary
+    the tile factors over every distinct tile of the padded bounds, and the
+    order of the parallel grid axes.  Each is scored by
+    :func:`traffic_bytes`; those whose working set (every stream at its
+    depth plus the accumulator, as :func:`schedule_is_legal` counts it)
+    exceeds ``VMEM_BUDGET_BYTES`` are dropped.  The least bytes win, then
+    the smaller working set.  ``None`` where the nest is not a plain
+    contraction, or where the winner saves less than
+    :data:`TRAFFIC_MIN_SAVING` of the default's bytes: the caller then runs
+    ``DEFAULT_SCHEDULE``.
+    """
+    return _traffic_pick(nest, operand_signature(operands), str(out_dtype))
+
+
+@functools.lru_cache(maxsize=256)
+def _traffic_pick(nest: LoopNest, sig: Tuple[Tuple[str, str], ...],
+                  out_dtype: str) -> Optional[Schedule]:
+    if not _nest_has_output(nest):
+        return None
+    try:
+        plan = _plan_for(nest, auto_lanes(nest))
+        base = lower_nest(plan, schedule=DEFAULT_SCHEDULE)
+    except (LoweringError, ValueError):
+        return None
+    if not _is_plain_contraction(base):
+        return None
+    sizes = {name: np.dtype(desc.rsplit(":", 1)[1]).itemsize
+             for name, desc in sig}
+    sizes[base.out_stream.name] = np.dtype(out_dtype).itemsize
+    # the working set at the widest element, as the accumulator is f32
+    itemsize = max(4, *sizes.values())
+    lanes_lvls = {s.levels[-1] for s in (*base.in_streams, base.out_stream)
+                  if s.levels}
+    row_lvls = {l for s in (*base.in_streams, base.out_stream)
+                for l in s.levels[:-1]} - lanes_lvls
+    pad = base.padded_bounds
+    d = DEFAULT_SCHEDULE
+    rows_f = _aligned_factors([pad[l] for l in row_lvls], d.rows,
+                              d.rows_tile_factor)
+    lanes_f = _aligned_factors([pad[l] for l in lanes_lvls], d.lanes,
+                               d.lanes_tile_factor)
+    default_bytes = traffic_bytes(base, sizes)
+    best = None
+    for order in [None] + _axis_orders(nest):
+        for rf, lf in itertools.product(rows_f, lanes_f):
+            sched = dataclasses.replace(d, rows_tile_factor=rf,
+                                        lanes_tile_factor=lf,
+                                        axis_order=order)
+            try:
+                lowered = lower_nest(plan, schedule=sched)
+            except (LoweringError, ValueError):
+                continue
+            ws = _stream_block_bytes(lowered, itemsize)
+            if ws > VMEM_BUDGET_BYTES:
+                continue
+            score = (traffic_bytes(lowered, sizes), ws)
+            if best is None or score < best[0]:
+                best = (score, sched)
+    if best is None or \
+            best[0][0] > (1 - TRAFFIC_MIN_SAVING) * default_bytes:
+        return None
+    return best[1]
 
 
 # --------------------------------------------------------------------------

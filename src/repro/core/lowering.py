@@ -1880,12 +1880,13 @@ def ssr_call(nest: LoopNest, body: Callable[..., jax.Array],
     planning, lowering and kernel emission in ``ssr.lower``.
     """
     tuned_key: Optional[str] = None
+    source = "explicit"
     if schedule is None and policy is DEFAULT_POLICY:
         from . import autotune as _autotune
 
         try:
             with TraceAnnotation("ssr.lookup"):
-                schedule = _autotune.lookup(
+                schedule, source = _autotune.resolve(
                     nest, operands, mode=mode,
                     out_dtype=str(jnp.dtype(out_dtype)))
         except resilience.fallback_error_types() as e:
@@ -1893,7 +1894,7 @@ def ssr_call(nest: LoopNest, body: Callable[..., jax.Array],
                              to_schedule="default")
             schedule = DEFAULT_SCHEDULE
         else:
-            if schedule != DEFAULT_SCHEDULE:
+            if source == "tuned" and schedule != DEFAULT_SCHEDULE:
                 tuned_key = _autotune.cache_key(
                     nest, operands, mode=mode,
                     out_dtype=str(jnp.dtype(out_dtype)))
@@ -1967,15 +1968,17 @@ def ssr_call(nest: LoopNest, body: Callable[..., jax.Array],
             fn, args = _kernel(sched)
         return fn(*args)
 
-    if tuned_key is None:
+    if tuned_key is None and source != "traffic":
         return _dispatch(_resolve_schedule(policy, schedule))
     try:
         return _dispatch(_resolve_schedule(policy, schedule))
     except resilience.fallback_error_types() as e:
-        from . import autotune as _autotune
+        # a tuned entry is quarantined; a traffic-chosen schedule has none
+        if tuned_key is not None:
+            from . import autotune as _autotune
 
-        _autotune.global_cache().quarantine(tuned_key)
-        _record_fallback("ssr_call", e, from_schedule="tuned",
+            _autotune.global_cache().quarantine(tuned_key)
+        _record_fallback("ssr_call", e, from_schedule=source,
                          to_schedule="default", key=tuned_key,
                          counter="degraded")
         return _dispatch(DEFAULT_SCHEDULE)
@@ -2033,9 +2036,11 @@ def ssr_chain_call(nests: Sequence[LoopNest],
 
         try:
             with TraceAnnotation("ssr.lookup"):
-                schedule = _autotune.lookup(
+                schedule, source = _autotune.resolve(
                     nests[0], operands, mode=mode,
                     out_dtype=str(jnp.dtype(out_dtype)))
+            if source == "traffic":       # tiles of one nest, not a chain's
+                schedule = DEFAULT_SCHEDULE
         except resilience.fallback_error_types() as e:
             _record_fallback("ssr_chain_call", e,
                              from_schedule="tuned-lookup",

@@ -53,6 +53,12 @@ from .stream import Direction
 
 # TPU v5e VMEM is 128 MiB/core; we budget conservatively for double buffering.
 VMEM_BUDGET_BYTES = 64 * 1024 * 1024
+#: Mosaic's default scoped-VMEM limit on v5e.  A kernel whose working set
+#: fits under it declares no limit and compiles as Mosaic chooses.
+MOSAIC_DEFAULT_VMEM_BYTES = 16 * 1024 * 1024
+#: The most a kernel declares: v5e's 128 MiB less room for Mosaic's own
+#: internal scratch.
+VMEM_LIMIT_CAP_BYTES = 112 * 1024 * 1024
 _LANE = 128
 _SUBLANE = {4: 8, 2: 16, 1: 32}  # min sublane tile per dtype byte width
 
@@ -176,11 +182,28 @@ def _unique_blocks(stream: BlockStream, grid: Tuple[int, ...]) -> int:
     return len(seen)
 
 
-def _compiler_params(dimension_semantics: Tuple[str, ...]):
-    """Mosaic compiler params: the grid axes' dimension semantics."""
+def vmem_limit_bytes(working_set: int) -> Optional[int]:
+    """The scoped-VMEM limit a kernel with this working set declares.
+
+    ``None`` where the working set fits Mosaic's default scoped limit: the
+    kernel then compiles exactly as Mosaic would choose.  Otherwise the
+    working set plus half again for Mosaic's own temporaries (the body's
+    block-sized values, such as a dot's result before it is added to the
+    accumulator), held under :data:`VMEM_LIMIT_CAP_BYTES`.
+    """
+    if working_set <= MOSAIC_DEFAULT_VMEM_BYTES:
+        return None
+    return min(VMEM_LIMIT_CAP_BYTES, working_set + working_set // 2)
+
+
+def _compiler_params(dimension_semantics: Optional[Tuple[str, ...]],
+                     vmem_limit: Optional[int] = None):
+    """Mosaic compiler params: the grid axes' dimension semantics and, where
+    the working set needs more than Mosaic's default, the VMEM limit."""
     from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
 
-    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=vmem_limit)
 
 
 def _flat_strides(grid: Tuple[int, ...]) -> Tuple[int, ...]:
@@ -210,7 +233,7 @@ def _pipelined_call(
     scratch_shapes: Sequence[Any],
     buffer_depth: Tuple[int, ...],
     interpret: bool,
-    extra_kwargs: dict,
+    call_kwargs: Callable[[Sequence[Any]], dict],
     name: Optional[str] = None,
 ) -> Callable[..., Any]:
     """Emit the explicit N-deep HBM→VMEM rotation (pipelined emission).
@@ -334,7 +357,7 @@ def _pipelined_call(
             scratch_shapes=rot + dma_sems + list(scratch_shapes),
             interpret=interpret,
             name=name,
-            **extra_kwargs,
+            **call_kwargs(arrays),
         )
         return call(*arrays)
 
@@ -377,6 +400,10 @@ def ssr_pallas(
 
     ``name`` is the kernel's family (``"gemm"``): the Pallas kernel is
     named ``ssr_<name>`` in the lowered program and on the device's trace.
+
+    On Mosaic, a kernel whose working set (the report's ``vmem_bytes`` plus
+    scratch, from the operands' dtypes) exceeds Mosaic's default scoped
+    VMEM declares the limit :func:`vmem_limit_bytes` gives it.
     """
     kernel_name = None if name is None else f"ssr_{name}"
     for s in in_streams:
@@ -417,55 +444,21 @@ def ssr_pallas(
                  and pipeline_supported()
                  and len(grid) >= 1 and math.prod(grid) > 1)
 
-    kwargs: dict = {}
     if pipelined:
         # rotation state threads through consecutive steps: every axis is
         # sequential regardless of the caller's declared semantics
         dimension_semantics = ("arbitrary",) * len(grid)
-    if dimension_semantics is not None and not interpret:
-        kwargs["compiler_params"] = _compiler_params(dimension_semantics)
-    if cost_estimate is not None:
-        kwargs["cost_estimate"] = cost_estimate
 
-    if pipelined:
-        fn = _pipelined_call(
-            body, grid=grid, in_streams=in_streams,
-            out_streams=out_streams, out_shapes=out_shapes,
-            scratch_shapes=scratch_shapes, buffer_depth=depths,
-            interpret=interpret, extra_kwargs=kwargs, name=kernel_name)
-    else:
-        call = pl.pallas_call(
-            body,
-            grid=grid,
-            in_specs=[s.spec() for s in in_streams],
-            out_specs=[s.spec() for s in out_streams]
-            if len(out_streams) != 1
-            else out_streams[0].spec(),
-            out_shape=list(out_shapes) if len(out_shapes) != 1
-            else out_shapes[0],
-            scratch_shapes=list(scratch_shapes),
-            interpret=interpret,
-            name=kernel_name,
-            **kwargs,
-        )
-
-        fn = jax.jit(call)
-
-    def report(dtypes: Sequence[Any]) -> StreamReport:
+    def working_set(dtypes: Sequence[Any]) -> Tuple[int, int]:
+        """(depth-buffered stream blocks, kernel-resident scratch) bytes:
+        inputs at their own FIFO depth, outputs at the deepest."""
         streams = (*in_streams, *out_streams)
         if len(dtypes) != len(streams):
             raise ValueError("one dtype per stream")
-        steps = math.prod(grid)
-        vmem = 0
-        streamed = 0
-        unique = 0
-        for idx, (s, dt) in enumerate(zip(streams, dtypes)):
-            bb = s.block_bytes(dt)
-            # inputs at their own FIFO depth; outputs at the deepest
-            d = depths[idx] if idx < len(in_streams) else max_depth
-            vmem += stream_vmem_bytes(bb, d)
-            streamed += bb * steps
-            unique += bb * _unique_blocks(s, grid)
+        vmem = sum(
+            stream_vmem_bytes(s.block_bytes(dt),
+                              depths[i] if i < len(in_streams) else max_depth)
+            for i, (s, dt) in enumerate(zip(streams, dtypes)))
         # Kernel-resident scratch (reduce accumulators, chained-intermediate
         # blocks) is single-buffered but counts against the same budget.
         scratch = 0
@@ -474,6 +467,58 @@ def ssr_pallas(
             dt = getattr(sc, "dtype", None)
             if shape is not None and dt is not None:
                 scratch += math.prod(shape) * jnp.dtype(dt).itemsize
+        return vmem, scratch
+
+    def call_kwargs(arrays: Sequence[Any]) -> dict:
+        """``pallas_call`` keywords for these operands: the cost estimate,
+        and on Mosaic the semantics and any VMEM limit the blocks need."""
+        kwargs: dict = {}
+        if cost_estimate is not None:
+            kwargs["cost_estimate"] = cost_estimate
+        if interpret:
+            return kwargs
+        limit = vmem_limit_bytes(sum(working_set(
+            [a.dtype for a in arrays] + [o.dtype for o in out_shapes])))
+        if dimension_semantics is not None or limit is not None:
+            kwargs["compiler_params"] = _compiler_params(dimension_semantics,
+                                                         limit)
+        return kwargs
+
+    if pipelined:
+        fn = _pipelined_call(
+            body, grid=grid, in_streams=in_streams,
+            out_streams=out_streams, out_shapes=out_shapes,
+            scratch_shapes=scratch_shapes, buffer_depth=depths,
+            interpret=interpret, call_kwargs=call_kwargs, name=kernel_name)
+    else:
+        def run(*arrays):
+            return pl.pallas_call(
+                body,
+                grid=grid,
+                in_specs=[s.spec() for s in in_streams],
+                out_specs=[s.spec() for s in out_streams]
+                if len(out_streams) != 1
+                else out_streams[0].spec(),
+                out_shape=list(out_shapes) if len(out_shapes) != 1
+                else out_shapes[0],
+                scratch_shapes=list(scratch_shapes),
+                interpret=interpret,
+                name=kernel_name,
+                **call_kwargs(arrays),
+            )(*arrays)
+
+        fn = jax.jit(run)
+
+    def report(dtypes: Sequence[Any]) -> StreamReport:
+        streams = (*in_streams, *out_streams)
+        vmem, scratch = working_set(dtypes)
+        steps = math.prod(grid)
+        streamed = 0
+        unique = 0
+        for s, dt in zip(streams, dtypes):
+            bb = s.block_bytes(dt)
+            streamed += bb * steps
+            unique += bb * _unique_blocks(s, grid)
         if vmem + scratch > VMEM_BUDGET_BYTES:
             raise ValueError(
                 f"VMEM working set {(vmem + scratch)/2**20:.1f} MiB exceeds "

@@ -82,9 +82,12 @@ COMPUTE_DTYPE = jnp.float32
 #: ``ssr.build`` spans (a cache miss up to the return of the new pipeline's
 #: first call: prepare, lookup, lowering, trace, compile); the lowering
 #: layer's builds run inside them and are not counted again.
+#: ``traffic_tiles`` counts builds whose schedule came from the HBM-traffic
+#: model (``autotune.traffic_schedule``), not from the tuned cache or
+#: ``DEFAULT_SCHEDULE``.
 DISPATCH_STATS: Dict[str, int] = {"builds": 0, "traces": 0, "calls": 0,
                                   "fallbacks": 0, "degraded": 0,
-                                  "build_ns": 0}
+                                  "build_ns": 0, "traffic_tiles": 0}
 
 
 #: Built-pipeline cap per kernel instance: epoch bumps retire old entries,
@@ -338,7 +341,8 @@ class NestKernel:
         return self._nest(static)
 
     def schedule_for(self, *args, **params) -> Schedule:
-        """The schedule this call would run: tuned (cache hit) or default."""
+        """The schedule this call would run: tuned (cache hit), chosen by
+        the HBM-traffic model, or the default."""
         operands, static, _final = self._prepare(*args, **params)
         out_dtype = "float32" if self._out_dtype is None else \
             str(jnp.dtype(self._out_dtype(static)))
@@ -360,8 +364,10 @@ class NestKernel:
         — this level owns the ladder.  A typed dispatch failure (injected
         fault, cache I/O, :class:`LoweringError`, compile error) under a
         *tuned* schedule quarantines the cache entry and rebuilds on the
-        default schedule; an explicit ``schedule=`` always propagates the
-        error (the caller pinned it, masking would hide their bug).
+        default schedule; under a traffic-chosen schedule it rebuilds on
+        the default with nothing to quarantine.  An explicit ``schedule=``
+        always propagates the error (the caller pinned it, masking would
+        hide their bug).
         """
         DISPATCH_STATS["calls"] += 1
         mosaic = _on_tpu() if interpret is None else not interpret
@@ -374,34 +380,41 @@ class NestKernel:
         except resilience.fallback_error_types() as e:
             if schedule is not None:
                 raise
-            key = self._quarantine_tuned(args, params)
-            if key is None:
+            rung = self._quarantine_tuned(args, params)
+            if rung is None:
                 raise
+            source, key = rung
             DISPATCH_STATS["degraded"] += 1
             resilience.record_fallback(
                 seam=resilience.classify(e), site=f"nest_kernel:{self.name}",
-                error=e, from_schedule="tuned", to_schedule="default",
+                error=e, from_schedule=source, to_schedule="default",
                 key=key)
             return self._dispatch(args, params, interpret, DEFAULT_SCHEDULE)
 
-    def _quarantine_tuned(self, args, params) -> Optional[str]:
-        """Sideline the committed tuned entry for this call, if any.
+    def _quarantine_tuned(self, args,
+                          params) -> Optional[Tuple[str, Optional[str]]]:
+        """The rung this call degrades from: ``(source, key)``.
 
-        Returns the quarantined cache key, or ``None`` when the call was
-        already running the default schedule (nothing tuned to degrade
-        from — the failure is genuine and must propagate).
+        A committed tuned entry is quarantined (``("tuned", key)``); a
+        traffic-chosen schedule has no entry to sideline
+        (``("traffic", None)``).  ``None`` when the call was already
+        running the default schedule (nothing to degrade from — the
+        failure is genuine and must propagate).
         """
         try:
             operands, static, _final = self._prepare(*args, **params)
             nest = self._nest(static)
             out_dtype = "float32" if self._out_dtype is None else \
                 str(jnp.dtype(self._out_dtype(static)))
-            tuned = autotune.lookup(nest, dict(operands), mode=self._mode,
-                                    out_dtype=out_dtype)
-            if tuned == DEFAULT_SCHEDULE:
+            sched, source = autotune.resolve(nest, dict(operands),
+                                             mode=self._mode,
+                                             out_dtype=out_dtype)
+            if sched == DEFAULT_SCHEDULE:
                 return None
-            return autotune.quarantine(nest, dict(operands), mode=self._mode,
-                                       out_dtype=out_dtype)
+            if source == "traffic":
+                return source, None
+            return source, autotune.quarantine(
+                nest, dict(operands), mode=self._mode, out_dtype=out_dtype)
         except Exception:  # re-probe failed: keep the original error
             return None
 
@@ -423,9 +436,10 @@ class NestKernel:
                 out_dtype = str(jnp.dtype(kw.get("out_dtype", jnp.float32)))
                 try:
                     with TraceAnnotation("ssr.lookup"):
-                        sched = autotune.lookup(nest, dict(operands),
-                                                mode=self._mode,
-                                                out_dtype=out_dtype)
+                        sched, source = autotune.resolve(
+                            nest, dict(operands), mode=self._mode,
+                            out_dtype=out_dtype)
+                    DISPATCH_STATS["traffic_tiles"] += int(source == "traffic")
                 except resilience.fallback_error_types() as e:
                     DISPATCH_STATS["fallbacks"] += 1
                     resilience.record_fallback(

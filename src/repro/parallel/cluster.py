@@ -210,23 +210,27 @@ def _shard_operand_sig(nests: Sequence[LoopNest],
 
 
 def _safe_lookup(nest: LoopNest, operands, *, mode: str, out_dtype,
-                 site: str) -> Optional[Schedule]:
+                 site: str, chain: bool = False) -> Optional[Schedule]:
     """Cache lookup that degrades to the default on typed dispatch faults.
 
     A broken cache must cost the cluster layer its tuned geometry, never
     the call: cache I/O errors and injected faults are recorded (one
     ``fallbacks`` tick + a :class:`FallbackEvent`) and resolve to ``None``
     — the default per-core schedule.  Returns ``None`` too on an ordinary
-    miss, matching the pre-resilience contract.
+    miss, matching the pre-resilience contract.  A ``chain`` keys on its
+    stage-0 nest, so it takes a tuned entry only, never that nest's
+    traffic-chosen tiles (as ``ssr_chain_call`` does).
     """
     try:
-        sched = autotune.lookup(nest, operands, mode=mode,
-                                out_dtype=str(jnp.dtype(out_dtype)))
+        sched, source = autotune.resolve(nest, operands, mode=mode,
+                                         out_dtype=str(jnp.dtype(out_dtype)))
     except resilience.fallback_error_types() as e:
         _record_fallback(site, e, from_schedule="tuned-lookup",
                          to_schedule="default")
         return None
-    return None if sched == autotune.DEFAULT_SCHEDULE else sched
+    if sched == autotune.DEFAULT_SCHEDULE or (chain and source == "traffic"):
+        return None
+    return sched
 
 
 def _core_schedule(subs: Sequence[LoopNest],
@@ -246,7 +250,7 @@ def _core_schedule(subs: Sequence[LoopNest],
     # A chain keys on its stage-0 sub-nest; the operand signature (which
     # spans every stage) disambiguates chains sharing a producer shape.
     return _safe_lookup(subs[0], sig, mode=mode, out_dtype=out_dtype,
-                        site="cluster:_core_schedule")
+                        site="cluster:_core_schedule", chain=len(subs) > 1)
 
 
 def _sharded_call(nests: Sequence[LoopNest], tile_fn: Callable,
@@ -359,7 +363,7 @@ def cluster_chain_call(nests: Sequence[LoopNest],
             # recorded provenance is the schedule the delegated call runs
             schedule = _safe_lookup(nests[0], operands, mode=mode,
                                     out_dtype=out_dtype,
-                                    site="cluster_chain_call")
+                                    site="cluster_chain_call", chain=True)
         _record_dispatch(schedule, 1, nests[0].bounds, policy)
         return ssr_chain_call(nests, bodies, operands, mode=mode,
                               out_dtype=out_dtype, policy=policy,

@@ -18,6 +18,7 @@ at tiny sizes in interpret mode on the CPU.
 
 import importlib.util
 import os
+import re
 import sys
 from pathlib import Path
 
@@ -28,9 +29,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import lowering
+from repro.core import autotune, compiler, lowering, ssr
 from repro.core.lowering import Schedule
-from repro.kernels import registry
+from repro.kernels import frontend, registry
 
 
 @pytest.fixture(scope="module")
@@ -143,6 +144,46 @@ def test_repaired_kernel_compiles_for_v5e(one_chip, name):
     text = _compiled_text(_ssr(name, **kw), [a.shape for a in args],
                           one_chip)
     assert "tpu_custom_call" in text
+
+
+def _scoped_vmem(lowered_text: str):
+    """The scoped-VMEM limit a lowered Mosaic kernel declares, or None."""
+    m = re.search(r"scoped_memory_configs.*?size\\22: (\d+)", lowered_text)
+    return None if m is None else int(m.group(1))
+
+
+#: GEMMs of the benchmark's cells, (A shape, B shape): qwen3-14b gate/up,
+#: yi-6b gate/up and down at 2048 tokens, one four-chip shard of qwen3-14b's
+#: gate/up at 8192 tokens.
+TRAFFIC_GEMMS = [
+    ("qwen3_gate_up", (2048, 5120), (5120, 17408)),
+    ("yi_gate_up", (2048, 4096), (4096, 11008)),
+    ("yi_down", (2048, 11008), (11008, 4096)),
+    ("qwen3_x4_gate_up_shard", (4096, 5120), (5120, 8704)),
+]
+
+
+@pytest.mark.parametrize("case,a,b", TRAFFIC_GEMMS,
+                         ids=[c[0] for c in TRAFFIC_GEMMS])
+def test_traffic_tiles_compile_for_v5e(one_chip, cold_kernels, case, a, b):
+    # the tiles the HBM-traffic model picks, with the VMEM limit they need
+    nest = compiler.gemm_nest(a[0], b[1], a[1])
+    sched = autotune.traffic_schedule(
+        nest, {"A": (a, "float32"), "B": (b, "float32")})
+    assert sched is not None
+    ws = autotune._stream_block_bytes(autotune._lower_candidate(nest, sched))
+    before = frontend.DISPATCH_STATS["traffic_tiles"]
+    lowered = _lowered(_ssr("gemm"), [a, b], one_chip)
+    assert frontend.DISPATCH_STATS["traffic_tiles"] == before + 1
+    assert _scoped_vmem(lowered.as_text()) == ssr.vmem_limit_bytes(ws)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("case", ["gemv", "attention"])
+def test_default_kernels_declare_no_vmem_limit(one_chip, case):
+    # kernels that fit Mosaic's default scoped VMEM compile as they did
+    _case, fn, shapes = next(c for c in MAIN_PATH if c[0] == case)
+    assert _scoped_vmem(_lowered(fn, shapes, one_chip).as_text()) is None
 
 
 # --------------------------------------------------------------------------
