@@ -3,7 +3,11 @@
 
 24 layers, d_model 2560, 32 heads (GQA kv=8), d_ff 6912, vocab 32000,
 SWA window 4096.  The bounded ring-buffer KV cache is what makes the
-long_500k decode cell runnable (DESIGN.md §4).
+long_500k decode cell runnable (DESIGN.md §4).  Through the stream
+compiler the window lives in the attention nest's band
+(``compiler.attention_nest(..., window=4096)``): the grid visits only the
+kv tiles the band meets, and the element mask runs only on the tiles at
+its edges.
 """
 
 from repro.models.config import ModelConfig, smoke_variant, uniform_dense_groups

@@ -54,6 +54,7 @@ from .ssr import (  # noqa: F401
 from . import nest_analysis  # noqa: F401
 from .compiler import (  # noqa: F401
     Allocation,
+    Band,
     COMBINE_COST,
     ChainDAG,
     ChainError,

@@ -106,9 +106,9 @@ def nest_signature(nest: LoopNest) -> str:
 
     Any change to bounds, refs (name/kind/coeffs/offset, plus the index
     stream + scale of an indirect ref, a halo window, or a non-default
-    accumulator kind) or per-level compute yields a different signature,
-    so editing a kernel's nest invalidates its cached schedules by
-    construction.  Refs without the newer features keep their older text
+    accumulator kind), per-level compute or a band yields a different
+    signature, so editing a kernel's nest invalidates its cached schedules
+    by construction.  Refs without the newer features keep their older text
     form, so existing cached schedules stay addressable.
     """
     def _ref_sig(r) -> str:
@@ -122,7 +122,11 @@ def nest_signature(nest: LoopNest) -> str:
         return sig
 
     refs = ";".join(_ref_sig(r) for r in nest.refs)
-    return f"b={nest.bounds}|refs={refs}|c={nest.compute_per_level}"
+    sig = f"b={nest.bounds}|refs={refs}|c={nest.compute_per_level}"
+    if nest.band is not None:
+        b = nest.band
+        sig += f"|band={b.outer}>{b.inner}:{b.lo}:{b.hi}"
+    return sig
 
 
 def operand_signature(operands: Dict[str, Any]) -> Tuple[Tuple[str, str], ...]:

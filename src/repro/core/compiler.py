@@ -25,7 +25,7 @@ from typing import List, Optional, Sequence, Tuple
 
 from . import isa
 from . import nest_analysis
-from .nest_analysis import LoopNest, MemRef  # noqa: F401  (re-exported API)
+from .nest_analysis import Band, LoopNest, MemRef  # noqa: F401  (re-exported API)
 from .stream import Direction, StreamSpec, MAX_DIMS  # noqa: F401
 
 DEFAULT_NUM_LANES = 2  # the implementation in the paper has two data movers
@@ -859,7 +859,8 @@ def stencil2d_nest(h: int, w: int, taps: int) -> LoopNest:
     )
 
 
-def attention_nest(sq: int, sk: int, d: int) -> LoopNest:
+def attention_nest(sq: int, sk: int, d: int, *, causal: bool = False,
+                   window: Optional[int] = None) -> LoopNest:
     """Executable nest for O[q,:] = softmax(Q·Kᵀ·scale)·V (flash form).
 
     Loop order (q, d, k): K/V walk the contraction level k with row
@@ -867,9 +868,21 @@ def attention_nest(sq: int, sk: int, d: int) -> LoopNest:
     repeats across k (§2.3 repeat register); O revisits each (q, d)
     block across the whole k walk with ``acc_kind="online_softmax"`` —
     ``lower_nest`` carries the flash-attention (max, sum, acc) triple in
-    VMEM and rescales on every k step (DESIGN.md §13).  The body owns
-    the score scaling and masking; the kernel owns the recurrence.
+    VMEM and rescales on every k step (DESIGN.md §13).
+
+    ``causal``/``window`` declare the keys each query keeps as a
+    :class:`Band` on k: with queries aligned to the end of the keys, query
+    i keeps key j ≤ i + (sk − sq), and under a window w also
+    j > i + (sk − sq) − w.  The lowering then visits only the kv tiles
+    the band meets; the body scales the scores and masks the band's edge
+    tiles, the kernel owns the recurrence.
     """
+    band = None
+    if causal or window is not None:
+        off = sk - sq
+        band = Band(outer=0, inner=2,
+                    lo=None if window is None else off - window + 1,
+                    hi=off if causal else None)
     return LoopNest(
         bounds=(sq, d, sk),
         refs=(MemRef("K", Direction.READ, (0, 1, d)),
@@ -878,6 +891,7 @@ def attention_nest(sq: int, sk: int, d: int) -> LoopNest:
               MemRef("O", Direction.WRITE, (d, 1, 0),
                      acc_kind="online_softmax")),
         compute_per_level=(0, 0, 2),
+        band=band,
     )
 
 

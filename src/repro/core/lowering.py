@@ -52,6 +52,7 @@ import collections
 import dataclasses
 import functools
 import math
+import operator
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -562,6 +563,59 @@ class HaloRead:
     windows: Tuple[int, ...]
 
 
+def _int_ops(x):
+    """(max, min, floor-div) for Python ints, or for int32 grid indices
+    (whose operands the band keeps non-negative, so division truncates)."""
+    if isinstance(x, int):
+        return max, min, operator.floordiv
+    return jnp.maximum, jnp.minimum, jax.lax.div
+
+
+@dataclasses.dataclass(frozen=True)
+class LoweredBand:
+    """A nest's :class:`~repro.core.nest_analysis.Band` at tile granularity.
+
+    Outer block i keeps inner tiles ``first(i) .. last(i)`` (:meth:`span`,
+    from the band's element edges and both tiles).  The banded level's
+    grid axis walks ``max(counts)`` steps, not the rectangle's ``n_inner``
+    tiles: step j maps to tile ``first(i) + j`` clamped to ``last(i)``
+    (:meth:`block`), so a step past the band repeats the previous block
+    index (no DMA) and the kernel skips it under ``pl.when``.
+    """
+
+    outer_level: int
+    inner_level: int
+    outer_axis: int              # grid positions of the two levels
+    inner_axis: int
+    outer_tile: int
+    inner_tile: int
+    lo: Optional[int]
+    hi: Optional[int]
+    n_inner: int                 # inner tiles of the rectangle
+    counts: Tuple[int, ...]      # inner tiles each outer block visits
+
+    def span(self, i):
+        """(first, last) inner tile of outer block ``i``: Python ints, or
+        int32 scalars when ``i`` is a grid index inside the kernel."""
+        mx, mn, div = _int_ops(i)
+        first = 0 if self.lo is None else div(
+            mx(i * self.outer_tile + self.lo, 0), self.inner_tile)
+        last = self.n_inner - 1
+        if self.hi is not None:
+            last = mn(div(mx((i + 1) * self.outer_tile - 1 + self.hi, 0),
+                          self.inner_tile), last)
+        return first, last
+
+    def block(self, i, j):
+        """The inner tile grid step ``j`` of outer block ``i`` reads."""
+        first, last = self.span(i)
+        return _int_ops(j)[1](first + j, last)
+
+    @property
+    def visits(self) -> int:
+        return sum(self.counts)
+
+
 @dataclasses.dataclass(frozen=True)
 class LoweredNest:
     """A StreamPlan with an output ref, lowered level-by-level.
@@ -573,7 +627,9 @@ class LoweredNest:
     positions** — declared ``arbitrary`` (sequential) so the accumulator
     carries, every other axis ``parallel``.  ``gathers`` are the plan's
     indirect refs: the body receives their gathered blocks *after* the
-    ``in_streams`` blocks, in declaration order.
+    ``in_streams`` blocks, in declaration order.  ``band`` is the nest's
+    band at tile granularity: the banded level's grid axis then walks the
+    band's tiles, not the rectangle's.
     """
 
     plan: StreamPlan
@@ -589,6 +645,7 @@ class LoweredNest:
     gathers: Tuple[IndirectGather, ...] = ()
     halos: Tuple[HaloRead, ...] = ()
     rescale: bool = False
+    band: Optional[LoweredBand] = None
 
     @property
     def semantics(self) -> Tuple[str, ...]:
@@ -598,6 +655,27 @@ class LoweredNest:
     @property
     def steps(self) -> int:
         return math.prod(self.grid)
+
+    def _others(self) -> int:
+        """Grid steps of the axes the band does not involve."""
+        b = self.band
+        return math.prod(n for k, n in enumerate(self.grid)
+                         if k not in (b.outer_axis, b.inner_axis))
+
+    @property
+    def visited_steps(self) -> int:
+        """Grid steps that compute: the band's tiles, or every step."""
+        if self.band is None:
+            return self.steps
+        return self.band.visits * self._others()
+
+    @property
+    def rect_steps(self) -> int:
+        """Grid steps the rectangle of the padded bounds would walk."""
+        if self.band is None:
+            return self.steps
+        return (self.grid[self.band.outer_axis] * self.band.n_inner
+                * self._others())
 
 
 def _storage_order_or_raise(ref, nest: LoopNest) -> Tuple[int, ...]:
@@ -620,6 +698,11 @@ def _nest_tiles(nest: LoopNest, orders: Dict[str, Tuple[int, ...]],
     a level appearing only in outer positions is a sublane level (aligned
     to ``sched.rows``, target ``rows·rows_tile_factor``); a level no
     stream varies with is a pure iteration axis (tile 1).
+
+    A lanes level that fits its tile target is one full-extent block,
+    unpadded, as whole-array block dims are legal at any width: a head
+    dim of 80 streams 80 lanes, not 128 of which 48 are zeros (1.6× the
+    bytes and the contraction, plus a pad and a trim around the kernel).
 
     A halo'd level (some ref reads a ``window[l] = w > 1`` neighbourhood)
     additionally needs ``w - 1`` overlap columns served by ONE +1-shifted
@@ -655,6 +738,10 @@ def _nest_tiles(nest: LoopNest, orders: Dict[str, Tuple[int, ...]],
             padded.append(b)
             continue
         need = halo_need.get(lvl, 0)
+        if role == "lanes" and not need and b <= target:
+            tiles.append(b)
+            padded.append(b)
+            continue
         if need:
             target = max(target, -(-need // align) * align)
         pb = -(-b // align) * align
@@ -687,10 +774,20 @@ def _grid_axis_order(sched: Schedule, d: int,
     return tuple(order)
 
 
+def _block_index(lvl: int, pos: Dict[int, int],
+                 band: Optional[LoweredBand]) -> Callable:
+    """Grid indices → level ``lvl``'s block index: its own grid axis, or
+    for the banded level the band's clamped tile of the outer block."""
+    if band is not None and lvl == band.inner_level:
+        return lambda g: band.block(g[band.outer_axis], g[band.inner_axis])
+    p = pos[lvl]
+    return lambda g: g[p]
+
+
 def _lower_nest_stream(alloc: Allocation, nest: LoopNest,
                        tiles: Tuple[int, ...], padded: Tuple[int, ...],
-                       policy: BlockPolicy,
-                       pos: Dict[int, int]) -> NestStream:
+                       policy: BlockPolicy, pos: Dict[int, int],
+                       band: Optional[LoweredBand] = None) -> NestStream:
     """One lane's level-mapped block walk.
 
     ``pos[lvl]`` is the grid-axis position of loop level ``lvl`` (identity
@@ -714,19 +811,19 @@ def _lower_nest_stream(alloc: Allocation, nest: LoopNest,
             "level-mapped block walk; fold it into the operand view")
     logical = tuple(nest.bounds[l] for l in order)
     pad_shape = tuple(padded[l] for l in order)
+    idx = tuple(_block_index(l, pos, band) for l in order)
     if len(order) == 1:
-        lvl = order[0]
-        block = (1, tiles[lvl])
+        block = (1, tiles[order[0]])
         layout = (1, pad_shape[0])
 
-        def index_map(*g, _p=pos[lvl]):
-            return (0, g[_p])
+        def index_map(*g):
+            return (0, idx[0](g))
     else:
         block = tuple(tiles[l] for l in order)
         layout = pad_shape
 
-        def index_map(*g, _ps=tuple(pos[l] for l in order)):
-            return tuple(g[p] for p in _ps)
+        def index_map(*g):
+            return tuple(f(g) for f in idx)
 
     return NestStream(
         name=ref.name,
@@ -804,6 +901,44 @@ def _lower_halo_streams(alloc: Allocation, nest: LoopNest,
             tuple(ref.window[lvl] for lvl in halo_lvls))
 
 
+def _lower_band(nest: LoopNest, rescale: bool, zaxes: Tuple[int, ...],
+                out_varying: Tuple[int, ...], tiles: Tuple[int, ...],
+                padded: Tuple[int, ...], pos: Dict[int, int],
+                gathers_or_halos: bool) -> LoweredBand:
+    """The nest's band at tile granularity, or a LoweringError where the
+    kernel cannot honour it.
+
+    Only the online-softmax kernel skips tiles: its body receives the
+    steps' element offsets and masks the pairs a band's edge tiles hold
+    beyond the band, which a plain accumulator's body cannot.
+    """
+    b = nest.band
+    if not rescale:
+        raise LoweringError(
+            f"band {b}: only the online_softmax accumulator lowers a band "
+            "(its body masks the band's edge tiles from their offsets)")
+    if zaxes != (b.inner,) or b.outer not in out_varying:
+        raise LoweringError(
+            f"band {b}: the banded level must be the output's contraction "
+            f"level {zaxes} and its outer level one the output varies with "
+            f"{out_varying}")
+    if gathers_or_halos:
+        raise LoweringError(
+            f"band {b}: a banded nest with gathers or halo reads is not "
+            "lowered")
+    n_outer = padded[b.outer] // tiles[b.outer]
+    proto = LoweredBand(
+        outer_level=b.outer, inner_level=b.inner, outer_axis=pos[b.outer],
+        inner_axis=pos[b.inner], outer_tile=tiles[b.outer],
+        inner_tile=tiles[b.inner], lo=b.lo, hi=b.hi,
+        n_inner=padded[b.inner] // tiles[b.inner], counts=())
+    counts = []
+    for i in range(n_outer):
+        first, last = proto.span(i)
+        counts.append(max(0, last - first + 1))
+    return dataclasses.replace(proto, counts=tuple(counts))
+
+
 def lower_nest(plan: StreamPlan,
                policy: BlockPolicy = DEFAULT_POLICY, *,
                schedule: Optional[Schedule] = None) -> LoweredNest:
@@ -821,6 +956,10 @@ def lower_nest(plan: StreamPlan,
     ``schedule`` (when given) wins over ``policy`` and additionally sets
     the per-level tile targets, the grid-axis order (parallel axes may
     permute; contraction axes stay trailing) and the accumulator dtype.
+
+    A nest with a band (causal or windowed attention) gets a contraction
+    axis as long as the most kv tiles any q block's band meets, and K/V
+    index maps that start at that block's first tile (:class:`LoweredBand`).
     """
     resilience.inject("lowering")
     sched = _resolve_schedule(policy, schedule)
@@ -888,6 +1027,13 @@ def lower_nest(plan: StreamPlan,
     axis_order = _grid_axis_order(sched, len(nest.bounds), zaxes)
     pos = {lvl: k for k, lvl in enumerate(axis_order)}
     grid = tuple(padded[l] // tiles[l] for l in axis_order)
+    band = None
+    if nest.band is not None:
+        band = _lower_band(nest, rescale, zaxes, out_varying, tiles, padded,
+                           pos, bool(ind_allocs) or any(
+                               a.ref.has_window() for a in dense_allocs))
+        grid = tuple(max(1, max(band.counts)) if k == band.inner_axis else n
+                     for k, n in enumerate(grid))
 
     if rescale:
         out_order = orders[out_ref.name]
@@ -925,7 +1071,8 @@ def lower_nest(plan: StreamPlan,
             halos.append(HaloRead(name=ref.name, slots=slots, axes=axes,
                                   tiles=htiles, windows=hwins))
         else:
-            s = _lower_nest_stream(a, nest, tiles, padded, policy, pos)
+            s = _lower_nest_stream(a, nest, tiles, padded, policy, pos,
+                                   band)
             if s.stream.direction == Direction.WRITE:
                 outs.append(s)
             else:
@@ -965,7 +1112,7 @@ def lower_nest(plan: StreamPlan,
                        contraction_axes=tuple(sorted(pos[z] for z in zaxes)),
                        schedule=sched, axis_order=axis_order,
                        padded_bounds=tuple(padded), gathers=tuple(gathers),
-                       halos=tuple(halos), rescale=rescale)
+                       halos=tuple(halos), rescale=rescale, band=band)
 
 
 # --------------------------------------------------------------------------
@@ -1133,10 +1280,14 @@ _PLAN_CACHES = (_plan_for, plan_stats, _chain_for, _dag_for, _lowered_for,
 #: runs keep both at zero.  ``pipelined`` counts ``ssr_call`` kernels
 #: built on the explicit N-deep DMA rotation (``fn.pipelined`` of
 #: :func:`~repro.core.ssr.ssr_pallas`) — proof that a ``buffer_depth > 2``
-#: schedule did not silently take the synchronous path.
+#: schedule did not silently take the synchronous path.  ``band_tiles``
+#: and ``rect_tiles`` count, over the attention (online-softmax) kernels
+#: built, the (q tile, kv tile) grid steps each visits and the steps the
+#: rectangle of its padded bounds would have had: equal without a band.
 DISPATCH_STATS: Dict[str, int] = {"builds": 0, "traces": 0, "calls": 0,
                                   "fallbacks": 0, "degraded": 0,
-                                  "pipelined": 0}
+                                  "pipelined": 0, "band_tiles": 0,
+                                  "rect_tiles": 0}
 
 
 def _record_fallback(site: str, error: BaseException, *,
@@ -1484,10 +1635,17 @@ def _build_nest_kernel(lowered: LoweredNest, body: Callable,
         # and the value block ``v`` (contraction-tile × lanes); the kernel
         # owns the m/l rescaling:  m' = max(m, rowmax(s)); α = e^{m−m'};
         # l' = αl + Σ e^{s−m'}; acc' = α·acc + e^{s−m'}·v; drain acc/l.
+        #
+        # With a band, the contraction axis walks the band's tiles: step j
+        # of outer block i is tile first(i) + j, and steps past last(i)
+        # (whose block index the index maps clamp, so nothing is fetched)
+        # leave the triple untouched — a clamped block is never accumulated
+        # twice.  ``offs`` carries the tile actually visited.
         z = zaxes[0]
         d = len(lowered.tiles)
         pos_of = {lvl: k for k, lvl in enumerate(lowered.axis_order)}
         n_rows = acc_shape[0]
+        band = lowered.band
 
         def kernel(*refs):
             in_refs = refs[:n_in]
@@ -1505,20 +1663,32 @@ def _build_nest_kernel(lowered: LoweredNest, body: Callable,
                 l_ref[...] = jnp.zeros_like(l_ref)
                 acc_ref[...] = jnp.zeros_like(acc_ref)
 
-            offs = tuple(pl.program_id(pos_of[l]) * lowered.tiles[l]
-                         for l in range(d))
-            s, v = body(*_blocks(in_refs, tab_refs), offs)
-            s = jnp.asarray(s, acc_dtype)
-            m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1,
-                                                      keepdims=True)
-            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-                p, jnp.asarray(v, acc_dtype),
-                (((1,), (0,)), ((), ())), preferred_element_type=acc_dtype)
-            m_ref[...] = m_new
+            offs = [pl.program_id(pos_of[l]) * lowered.tiles[l]
+                    for l in range(d)]
+
+            def _update():
+                s, v = body(*_blocks(in_refs, tab_refs), tuple(offs))
+                s = jnp.asarray(s, acc_dtype)
+                m_prev = m_ref[...]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=-1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1,
+                                                          keepdims=True)
+                acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                    p, jnp.asarray(v, acc_dtype),
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=acc_dtype)
+                m_ref[...] = m_new
+
+            if band is None:
+                _update()
+            else:
+                lo_t, hi_t = band.span(pl.program_id(band.outer_axis))
+                tile = lo_t + pl.program_id(band.inner_axis)
+                offs[band.inner_level] = tile * band.inner_tile
+                pl.when(tile <= hi_t)(_update)
 
             @pl.when(last)
             def _drain():
@@ -1587,6 +1757,9 @@ def _build_nest_kernel(lowered: LoweredNest, body: Callable,
         scratch_shapes=scratch,
         interpret=interpret,
         dimension_semantics=lowered.semantics,
+        # a band's K/V maps are affine between clamps, which the affine
+        # probe cannot tell from a non-affine map; _lower_band derives them
+        validate=lowered.band is None,
         buffer_depth=depths,
         name=name,
     )
@@ -1960,6 +2133,9 @@ def ssr_call(nest: LoopNest, body: Callable[..., jax.Array],
         fn = jax.jit(_named(pipeline, name and f"ssr_{name}"))
         DISPATCH_STATS["builds"] += 1
         DISPATCH_STATS["pipelined"] += int(kernel.pipelined)
+        if has_output and lowered.rescale:
+            DISPATCH_STATS["band_tiles"] += lowered.visited_steps
+            DISPATCH_STATS["rect_tiles"] += lowered.rect_steps
         _kernel_cache_put(key, fn)
         return fn, args
 

@@ -93,12 +93,48 @@ class MemRef:
 
 
 @dataclasses.dataclass(frozen=True)
+class Band:
+    """A level whose bounds are affine in an outer level's index.
+
+    Level ``inner``'s index j runs over ``[i + lo, i + hi]``, clipped to
+    ``[0, bounds[inner])``, where i is level ``outer``'s index — the
+    paper's affine stream with bounds that depend on the outer index,
+    which a rectangle of ``bounds`` cannot express.  ``None`` leaves that
+    edge open.  Causal attention over keys aligned to the end of the
+    queries is ``hi = sk − sq``; a sliding window of w adds
+    ``lo = hi − w + 1``.
+    """
+
+    outer: int
+    inner: int
+    lo: Optional[int] = None
+    hi: Optional[int] = None
+
+    def extent(self, i: int, n_inner: int) -> int:
+        """Inner indices the band keeps for outer index ``i``."""
+        first = 0 if self.lo is None else max(0, i + self.lo)
+        last = n_inner - 1 if self.hi is None else min(n_inner - 1,
+                                                       i + self.hi)
+        return max(0, last - first + 1)
+
+    def pairs(self, n_outer: int, n_inner: int) -> int:
+        """(outer, inner) index pairs the band keeps."""
+        return sum(self.extent(i, n_inner) for i in range(n_outer))
+
+
+@dataclasses.dataclass(frozen=True)
 class LoopNest:
-    """A perfect loop nest with known bounds (outermost first)."""
+    """A perfect loop nest with known bounds (outermost first).
+
+    ``band`` (optional) narrows one level's range to an affine window of
+    an outer level's index (:class:`Band`); every other level is
+    rectangular.
+    """
 
     bounds: Tuple[int, ...]
     refs: Tuple[MemRef, ...]
     compute_per_level: Tuple[int, ...]  # useful ops per body, per level
+    band: Optional[Band] = None
 
     def __post_init__(self) -> None:
         if len(self.bounds) > MAX_DIMS:
@@ -108,6 +144,16 @@ class LoopNest:
             )
         if len(self.compute_per_level) != len(self.bounds):
             raise ValueError("compute_per_level must match nest depth")
+        b = self.band
+        if b is not None:
+            if not 0 <= b.outer < b.inner < len(self.bounds):
+                raise ValueError(
+                    f"band {b}: needs 0 <= outer < inner < nest depth "
+                    f"({len(self.bounds)})")
+            if b.lo is None and b.hi is None:
+                raise ValueError(f"band {b}: give lo, hi or both")
+            if b.lo is not None and b.hi is not None and b.lo > b.hi:
+                raise ValueError(f"band {b}: lo > hi keeps no index")
         by_name = {r.name: r for r in self.refs}
         for r in self.refs:
             if r.acc_kind not in ("sum", "online_softmax"):
@@ -294,10 +340,18 @@ def instr_counts(nest: LoopNest,
 
 
 def nest_compute(nest: LoopNest) -> int:
-    """Useful ops of one nest execution: Σ_i I_i · Π_{n≤i} L_n."""
+    """Useful ops of one nest execution: Σ_i I_i · Π_{n≤i} L_n, where a
+    banded level's trip count is the band's (outer, inner) pairs in place
+    of its outer level's and its own bounds."""
     prod, total = 1, 0
-    for Li, Ii in zip(nest.bounds, nest.compute_per_level):
-        prod *= Li
+    band = nest.band
+    for lvl, (Li, Ii) in enumerate(zip(nest.bounds,
+                                       nest.compute_per_level)):
+        if band is not None and lvl == band.inner:
+            n_outer = nest.bounds[band.outer]
+            prod = prod // n_outer * band.pairs(n_outer, Li)
+        else:
+            prod *= Li
         total += Ii * prod
     return total
 

@@ -14,8 +14,11 @@ the flash recurrence (DESIGN.md §13):
 * the kv grid axis is ``arbitrary`` (sequential), the rest ``parallel``;
   the pipeline prefetches the next K/V tile during this tile's two
   matmuls — the data-mover run-ahead that gives the paper its 3×;
-* causal/sliding-window masks are *static* index arithmetic in the body
-  (iota against the grid offsets) — data-oblivious, as SSR requires.
+* causal and sliding-window masks live in the nest: ``attention_nest``
+  declares them as a band on the kv level, so the grid visits only the kv
+  tiles the band meets.  The body's element mask (iota against the grid
+  offsets — static index arithmetic, data-oblivious as SSR requires) runs
+  only on the tiles at the band's edges and the padded last kv tile.
 
 Supports MHA/GQA (q heads grouped over kv heads via an outer vmap), causal
 and sliding-window (h2o-danube) masking.
@@ -50,8 +53,8 @@ def _prepare(q, k, v, causal=False, window=None, scale=None,
 
 
 def _nest(static):
-    sq, sk, d = static[:3]
-    return compiler.attention_nest(sq, sk, d)
+    sq, sk, d, causal, window = static[:5]
+    return compiler.attention_nest(sq, sk, d, causal=causal, window=window)
 
 
 def _body(static):
@@ -65,15 +68,26 @@ def _body(static):
         s = jax.lax.dot_general(
             promote(q_blk), promote(k_blk), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        rows = offs[0] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-            + offs_rc
-        cols = offs[2] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = cols < sk                     # padded kv columns
+        bq, bk = s.shape
+        r0, c0 = offs[0] + offs_rc, offs[2]
+
+        def masked(s):
+            rows = r0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            cols = c0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = cols < sk                     # padded kv columns
+            if causal:
+                mask = jnp.logical_and(mask, cols <= rows)
+            if window is not None:
+                mask = jnp.logical_and(mask, cols > rows - window)
+            return jnp.where(mask, s, _NEG_INF)
+
+        # a tile inside the band, short of the padding, keeps every pair
+        edge = c0 + bk > sk
         if causal:
-            mask = jnp.logical_and(mask, cols <= rows)
+            edge = jnp.logical_or(edge, c0 + bk - 1 > r0)
         if window is not None:
-            mask = jnp.logical_and(mask, cols > rows - window)
-        return jnp.where(mask, s, _NEG_INF), v_blk
+            edge = jnp.logical_or(edge, c0 <= r0 + bq - 1 - window)
+        return jax.lax.cond(edge, masked, lambda s: s, s), v_blk
 
     return body
 
@@ -106,12 +120,13 @@ def _entry() -> KernelEntry:
         return ref.attention_ref(q, k, v, **kw).astype(q.dtype)
 
     def example(rng, odd: bool = False):
-        sq, sk = (128, 256) if odd else (256, 256)
-        d = 64
+        # the odd size is windowed, over a head dim short of a lane tile
+        sq, sk, d = (128, 256, 80) if odd else (256, 256, 64)
+        kw = {"causal": True, "window": 100} if odd else {"causal": True}
         return ((jnp.asarray(rng.standard_normal((sq, d)), jnp.float32),
                  jnp.asarray(rng.standard_normal((sk, d)), jnp.float32),
                  jnp.asarray(rng.standard_normal((sk, d)), jnp.float32)),
-                {"causal": True})
+                kw)
 
     return KernelEntry(name="attention", ssr=ssr_flash_attention, ref=_ref,
                        example=example, tol={"rtol": 2e-4, "atol": 2e-4},

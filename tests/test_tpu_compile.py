@@ -99,6 +99,10 @@ MAIN_PATH = [
     ("gemm", _ssr("gemm"), [(2048, 5120), (5120, 17408)]),
     ("attention", _heads(_ssr("attention", causal=True)),
      [(40, 2048, 128), (8, 2048, 128), (8, 2048, 128)]),
+    # h2o-danube-1.8b: window 4096 over 16384 tokens, head dim 80 unpadded
+    ("attention_window", _heads(_ssr("attention", causal=True, window=4096),
+                                heads=32),
+     [(32, 16384, 80), (8, 16384, 80), (8, 16384, 80)]),
     ("gemv", _ssr("gemv"), [(5120, 17408), (17408,)]),
     ("gemv_depth3", _ssr("gemv", schedule=Schedule(buffer_depth=3)),
      [(5120, 17408), (17408,)]),
@@ -110,7 +114,8 @@ MAIN_PATH = [
 
 
 #: The kernel family of each main-path case compiled through NestKernel.
-FAMILY = {"gemm": "gemm", "attention": "attention", "gemv": "gemv",
+FAMILY = {"gemm": "gemm", "attention": "attention",
+          "attention_window": "attention", "gemv": "gemv",
           "gemv_depth3": "gemv", "reduction": "reduction",
           "stencil1d": "stencil1d"}
 
