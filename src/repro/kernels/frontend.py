@@ -84,10 +84,13 @@ COMPUTE_DTYPE = jnp.float32
 #: layer's builds run inside them and are not counted again.
 #: ``traffic_tiles`` counts builds whose schedule came from the HBM-traffic
 #: model (``autotune.traffic_schedule``), not from the tuned cache or
-#: ``DEFAULT_SCHEDULE``.
+#: ``DEFAULT_SCHEDULE``.  ``vmap_launches`` counts warm calls whose
+#: arguments carried ``jax.vmap``'s batch tracers and were served by a
+#: cached batched pipeline (:class:`_Peeled`), one per call.
 DISPATCH_STATS: Dict[str, int] = {"builds": 0, "traces": 0, "calls": 0,
                                   "fallbacks": 0, "degraded": 0,
-                                  "build_ns": 0, "traffic_tiles": 0}
+                                  "build_ns": 0, "traffic_tiles": 0,
+                                  "vmap_launches": 0}
 
 
 #: Built-pipeline cap per kernel instance: epoch bumps retire old entries,
@@ -149,6 +152,119 @@ def _call_key(args: tuple, params: Dict[str, Any]) -> Any:
         for a in args)
     param_key = tuple(sorted((k, _static_key(v)) for k, v in params.items()))
     return arg_key, param_key
+
+
+# -- calls under jax.vmap -----------------------------------------------------
+#
+# Called eagerly under ``jax.vmap``, a jitted pipeline receives batch
+# tracers, and JAX re-runs pjit's batching rule over the pipeline's jaxpr
+# (Pallas's included) and dispatches through the Python pjit path on every
+# call.  ``NestKernel`` instead peels the tracers (:class:`_Peeled`) and
+# calls a cached ``jax.jit(jax.vmap(pipeline))`` on the values under them.
+# The batch tracer, its trace and the tracing context are JAX internals,
+# read only in ``_Peeled``; without them every call takes the per-example
+# path.
+
+try:
+    from jax._src import core as _jax_core
+    from jax._src.interpreters.batching import BatchTrace as _BatchTrace
+    from jax._src.interpreters.batching import BatchTracer as _BatchTracer
+    _NO_AXIS_NAME = _jax_core.no_axis_name
+    _TRACE_CTX = _jax_core.trace_ctx
+    _EVAL_TRACE = _jax_core.eval_trace
+    _TOP_AXIS_ENV = _jax_core.top_axis_env
+    _Tracer = _jax_core.Tracer
+except (ImportError, AttributeError):  # pragma: no cover - other JAX
+    _BatchTracer = None
+
+
+class _Peeled:
+    """A call's arguments with the unnamed ``jax.vmap`` levels they share
+    peeled off.
+
+    ``levels`` lists ``(trace, axes)`` innermost first, one per enclosing
+    vmap that maps some argument, with each argument's batch axis at that
+    level (``None`` where it does not map it, and for every static
+    argument); ``values`` are the arguments under every peeled level;
+    ``base`` is the trace the batched call runs in (eager, or a
+    ``jax.jit`` being traced).
+    """
+
+    __slots__ = ("levels", "values", "base")
+
+    def __init__(self, levels, values, base):
+        self.levels, self.values, self.base = levels, values, base
+
+    @classmethod
+    def of(cls, args: Sequence[Any],
+           params: Dict[str, Any]) -> Optional["_Peeled"]:
+        """The call's peeled form, or ``None`` when it is not peeled: no
+        argument is a batch tracer of the current trace (a concrete call
+        costs one type check an argument), a parameter is a tracer, or
+        this JAX lacks what is read here."""
+        if _BatchTracer is None or \
+                not any(type(a) is _BatchTracer for a in args) or \
+                any(isinstance(v, _Tracer) for v in params.values()):
+            return None
+        try:
+            trace, vals, levels = _TRACE_CTX.trace, tuple(args), []
+            while (type(trace) is _BatchTrace
+                   and trace.axis_data.name is _NO_AXIS_NAME
+                   and trace.axis_data.spmd_name is None
+                   and trace.axis_data.explicit_mesh_axis is None):
+                inner, axes = zip(*map(trace.to_batch_info, vals))
+                if all(d is None for d in axes) or \
+                        any(d is not None and type(d) is not int
+                            for d in axes):
+                    break
+                levels.append((trace, axes))
+                vals, trace = inner, trace.parent_trace
+        except AttributeError:  # pragma: no cover - other JAX
+            return None
+        return cls(tuple(levels), vals, trace) if levels else None
+
+    def axes(self) -> Tuple[Tuple[Optional[int], ...], ...]:
+        """Each level's batch axes, innermost first: part of the key."""
+        return tuple(axes for _trace, axes in self.levels)
+
+    def batch(self, pipeline: Callable) -> Callable:
+        """The per-example ``pipeline`` (over the array arguments) under
+        each peeled level, innermost first: batched once, when the jitted
+        program is traced."""
+        idx = [i for i, a in enumerate(self.values) if _is_arraylike(a)]
+        for _trace, axes in self.levels:
+            pipeline = jax.vmap(pipeline, in_axes=tuple(axes[i] for i in idx))
+        return pipeline
+
+    def call(self, fn: Callable, arrays: Sequence[Any]) -> Any:
+        """Call the batched pipeline ``fn`` in ``base`` and wrap each output
+        back as a batch tracer of every peeled level, batch axis 0.
+
+        An eager call under unnamed vmaps runs at JAX's top level (eval
+        trace, empty axis env), where a warm jitted call takes the C++
+        fast path.
+        """
+        ctx = _TRACE_CTX
+        trace, env = ctx.trace, ctx.axis_env
+        top = self.base is _EVAL_TRACE and not (
+            env.axis_sizes or env.spmd_axis_names
+            or env.explicit_mesh_axis_names)
+        ctx.set_trace(self.base)
+        if top:
+            ctx.set_axis_env(_TOP_AXIS_ENV)
+        try:
+            out = fn(*arrays)
+        finally:
+            ctx.set_trace(trace)
+            if top:
+                ctx.set_axis_env(env)
+
+        def wrap(o):
+            for level, _axes in reversed(self.levels):
+                o = _BatchTracer(level, o, 0)
+            return o
+
+        return jax.tree.map(wrap, out)
 
 
 def promote(x: jax.Array) -> jax.Array:
@@ -420,59 +536,76 @@ class NestKernel:
 
     def _dispatch(self, args, params, interpret: Optional[bool],
                   schedule: Optional[Schedule]):
-        key = (_call_key(args, params), schedule, interpret,
-               autotune.epoch() if schedule is None else -1)
+        # Under jax.vmap the key holds the batched shapes of the values
+        # under the tracers and each peeled level's axes.
+        peeled = _Peeled.of(args, params)
+        values = args if peeled is None else peeled.values
+        key = (_call_key(values, params), schedule, interpret,
+               autotune.epoch() if schedule is None else -1,
+               None if peeled is None else peeled.axes())
+        arrays = [a for a in values if _is_arraylike(a)]
         fn = self._cache.get(key)
         if fn is not None:
             with TraceAnnotation("ssr.launch"):
-                return fn(*[a for a in args if _is_arraylike(a)])
+                if peeled is None:
+                    return fn(*arrays)
+                DISPATCH_STATS["vmap_launches"] += 1
+                return peeled.call(fn, arrays)
         with _build_span():
-            operands, static, _final = self._prepare(*args, **params)
-            nest = self._nest(static)
-            kw = {} if self._out_dtype is None else \
-                {"out_dtype": self._out_dtype(static)}
-            sched = schedule
-            if sched is None:
-                out_dtype = str(jnp.dtype(kw.get("out_dtype", jnp.float32)))
-                try:
-                    with TraceAnnotation("ssr.lookup"):
-                        sched, source = autotune.resolve(
-                            nest, dict(operands), mode=self._mode,
-                            out_dtype=out_dtype)
-                    DISPATCH_STATS["traffic_tiles"] += int(source == "traffic")
-                except resilience.fallback_error_types() as e:
-                    DISPATCH_STATS["fallbacks"] += 1
-                    resilience.record_fallback(
-                        seam=resilience.classify(e),
-                        site=f"nest_kernel:{self.name}", error=e,
-                        from_schedule="tuned-lookup", to_schedule="default")
-                    sched = DEFAULT_SCHEDULE
-            arr_idx = tuple(i for i, a in enumerate(args)
-                            if _is_arraylike(a))
-            # static positions only — see the _KernelBase note: closing
-            # over first-call arrays would pin their buffers
-            statics = tuple(None if _is_arraylike(a) else a for a in args)
-
-            def pipeline(*arrs, _st=statics, _idx=arr_idx, _sched=sched):
-                DISPATCH_STATS["traces"] += 1
-                full = list(_st)
-                for i, a in zip(_idx, arrs):
-                    full[i] = a
-                ops, s, final = self._prepare(*full, **params)
-                okw = {} if self._out_dtype is None else \
-                    {"out_dtype": self._out_dtype(s)}
-                out = ssr_call(self._nest(s), self._body(s), dict(ops),
-                               mode=self._mode, schedule=_sched,
-                               interpret=interpret, name=self.name, **okw)
-                return self._finish(out, final) if self._finish else out
-
+            pipeline = self._pipeline(args, params, interpret, schedule)
+            if peeled is not None:
+                pipeline = peeled.batch(pipeline)
             resilience.inject("compile")
             fn = jax.jit(_named(pipeline, f"ssr_{self.name}"))
             DISPATCH_STATS["builds"] += 1
             if len(self._cache) >= _PIPELINE_CACHE_MAX:
                 self._cache.clear()
             self._cache[key] = fn
-            return fn(*[a for a in args if _is_arraylike(a)])
+            return fn(*arrays) if peeled is None else peeled.call(fn, arrays)
+
+    def _pipeline(self, args, params, interpret: Optional[bool],
+                  schedule: Optional[Schedule]) -> Callable:
+        """The per-example prepare→ssr_call→finish function over a call's
+        array arguments, its schedule resolved here (tuned, traffic-chosen
+        or default)."""
+        operands, static, _final = self._prepare(*args, **params)
+        nest = self._nest(static)
+        sched = schedule
+        if sched is None:
+            out_dtype = "float32" if self._out_dtype is None else \
+                str(jnp.dtype(self._out_dtype(static)))
+            try:
+                with TraceAnnotation("ssr.lookup"):
+                    sched, source = autotune.resolve(
+                        nest, dict(operands), mode=self._mode,
+                        out_dtype=out_dtype)
+                DISPATCH_STATS["traffic_tiles"] += int(source == "traffic")
+            except resilience.fallback_error_types() as e:
+                DISPATCH_STATS["fallbacks"] += 1
+                resilience.record_fallback(
+                    seam=resilience.classify(e),
+                    site=f"nest_kernel:{self.name}", error=e,
+                    from_schedule="tuned-lookup", to_schedule="default")
+                sched = DEFAULT_SCHEDULE
+        arr_idx = tuple(i for i, a in enumerate(args) if _is_arraylike(a))
+        # static positions only — see the _KernelBase note: closing over
+        # first-call arrays would pin their buffers
+        statics = tuple(None if _is_arraylike(a) else a for a in args)
+
+        def pipeline(*arrs, _st=statics, _idx=arr_idx, _sched=sched):
+            DISPATCH_STATS["traces"] += 1
+            full = list(_st)
+            for i, a in zip(_idx, arrs):
+                full[i] = a
+            ops, s, final = self._prepare(*full, **params)
+            okw = {} if self._out_dtype is None else \
+                {"out_dtype": self._out_dtype(s)}
+            out = ssr_call(self._nest(s), self._body(s), dict(ops),
+                           mode=self._mode, schedule=_sched,
+                           interpret=interpret, name=self.name, **okw)
+            return self._finish(out, final) if self._finish else out
+
+        return pipeline
 
 
 class StreamKernel(_KernelBase):

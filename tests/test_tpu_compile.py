@@ -191,6 +191,33 @@ def test_default_kernels_declare_no_vmem_limit(one_chip, case):
     assert _scoped_vmem(_lowered(fn, shapes, one_chip).as_text()) is None
 
 
+#: Yi-6B attention as the benchmark's stage calls it: decode, one vmap over
+#: 4 KV heads (8 stacked queries over a 4096-position cache); prefill, the
+#: group's 8 q heads inside and the KV heads outside.
+YI_VMAPPED = {
+    "decode": (lambda f: jax.vmap(f),
+               [(4, 8, 128), (4, 4096, 128), (4, 4096, 128)], {}),
+    "prefill": (lambda f: jax.vmap(jax.vmap(f, in_axes=(0, None, None))),
+                [(4, 8, 2048, 128), (4, 2048, 128), (4, 2048, 128)],
+                {"causal": True}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(YI_VMAPPED))
+def test_peeled_vmap_is_the_same_mosaic_program(one_chip, monkeypatch, case):
+    # a call under vmap, served by a cached jit(vmap(pipeline)), lowers to
+    # the Mosaic program that JAX's batching of the pipeline gives
+    wrap, shapes, kw = YI_VMAPPED[case]
+    peeled = _lowered(wrap(_ssr("attention", **kw)), shapes,
+                      one_chip).as_text()
+    monkeypatch.setattr(frontend._Peeled, "of",
+                        classmethod(lambda cls, args, params: None))
+    unpeeled = _lowered(wrap(_ssr("attention", **kw)), shapes,
+                        one_chip).as_text()
+    assert "tpu_custom_call" in peeled
+    assert peeled == unpeeled
+
+
 # --------------------------------------------------------------------------
 # chip_smoke.py's phases at tiny sizes, interpreted on the CPU
 # --------------------------------------------------------------------------
